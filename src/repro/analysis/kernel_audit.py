@@ -19,7 +19,7 @@ claims, and fail when the declaration is LOOSER than the derivation:
 
 - **paged-attention scratch** (`paged_attention` caps): the declared
   online-softmax scratch dims must equal the canonical derivation —
-  running max/denom one f32 per (K, G, W) triple, accumulator adding the
+  running max/denom one f32 per (K, W*G) pair, accumulator adding the
   head dim — and the per-grid-step working set must fit VMEM at the
   reference dims.
 
@@ -79,8 +79,8 @@ def derived_deltaw_vmem(caps: Dict) -> int:
     return DOUBLE_BUFFER * (basis + tile + entries)
 
 
-_CANONICAL_SCRATCH = {"m": ("K", "G", "W"), "l": ("K", "G", "W"),
-                      "acc": ("K", "G", "W", "dh")}
+_CANONICAL_SCRATCH = {"m": ("K", "W*G"), "l": ("K", "W*G"),
+                      "acc": ("K", "W*G", "dh")}
 
 
 def derived_paged_vmem(caps: Dict) -> int:

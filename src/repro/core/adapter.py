@@ -322,12 +322,11 @@ class FourierFT(AdapterMethod):
 # ---------------------------------------------------------------------------
 
 def _dct_bases(entries: jax.Array, d1: int, d2: int):
-    u = entries[0].astype(jnp.float32)
-    v = entries[1].astype(jnp.float32)
-    j = jnp.arange(d1, dtype=jnp.float32)[:, None]
-    k = jnp.arange(d2, dtype=jnp.float32)[:, None]
-    c1 = jnp.cos((np.pi / (2.0 * d1)) * (2.0 * j + 1.0) * u[None, :])
-    c2 = jnp.cos((np.pi / (2.0 * d2)) * (2.0 * k + 1.0) * v[None, :])
+    # cos(π(2j+1)u/2d) has period 4d in the integer product (2j+1)·u
+    c1 = jnp.cos((np.pi / (2.0 * d1)) * fourierft.phase_products(
+        d1, entries[0], 2, 1, 4 * d1))
+    c2 = jnp.cos((np.pi / (2.0 * d2)) * fourierft.phase_products(
+        d2, entries[1], 2, 1, 4 * d2))
     return c1, c2                                              # (d1,n) (d2,n)
 
 

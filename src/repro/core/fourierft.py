@@ -88,15 +88,26 @@ def sample_entries(d1: int, d2: int, n: int, seed: int = 2024, *,
 # Fourier bases (traced; generated on the fly, never checkpointed)
 # ---------------------------------------------------------------------------
 
+def phase_products(d: int, e: jax.Array, mul: int = 1, add: int = 0,
+                   period: int = 0) -> jax.Array:
+    """(mul·j + add)·e_l for rows j in [0, d), as float32 (d, n), reduced
+    mod `period` (default d) in int32 while the product cannot overflow —
+    the same exact phases the Pallas kernels compute, so cos/sin see
+    arguments of one period at full float32 precision. Vocab-sized grids
+    past the int32 bound keep the unreduced float product."""
+    period = period or d
+    rows = mul * jnp.arange(d, dtype=jnp.int32)[:, None] + add
+    if (mul * (d - 1) + add) * (d - 1) < 2 ** 31:
+        return ((rows * e[None, :].astype(jnp.int32)) % period).astype(
+            jnp.float32)
+    return rows.astype(jnp.float32) * e[None, :].astype(jnp.float32)
+
+
 def fourier_angles(entries: jax.Array, d1: int, d2: int):
     """Phase grids for the selected entries: θ[j,l] = 2π·j·u_l/d1 (d1, n)
     and φ[k,l] = 2π·k·v_l/d2 (d2, n)."""
-    u = entries[0].astype(jnp.float32)   # (n,)
-    v = entries[1].astype(jnp.float32)
-    j = jnp.arange(d1, dtype=jnp.float32)[:, None]
-    k = jnp.arange(d2, dtype=jnp.float32)[:, None]
-    theta = (TWO_PI / d1) * (j * u[None, :])
-    phi = (TWO_PI / d2) * (k * v[None, :])
+    theta = (TWO_PI / d1) * phase_products(d1, entries[0])
+    phi = (TWO_PI / d2) * phase_products(d2, entries[1])
     return theta, phi
 
 

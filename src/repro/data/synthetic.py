@@ -7,8 +7,10 @@ straggler re-assignment, with no iterator state to checkpoint and no data
 loss/replay.
 
 Sequences are drawn from a fixed first-order Markov "teacher" (seeded
-transition table), so models measurably learn; fine-tuning benchmarks use a
-second teacher seed as the "downstream task".
+transition logits), so models measurably learn; fine-tuning benchmarks use a
+second teacher seed as the "downstream task". A row of the teacher is drawn
+from the PRNG when a token needs it, never stored as a (vocab, vocab) table:
+at a 152k vocab that table would be 92 GB.
 """
 from __future__ import annotations
 
@@ -19,20 +21,30 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+CONCENTRATION = 1.5
 
-def markov_table(vocab: int, task_seed: int, concentration: float = 1.5):
+
+def markov_rows(task_seed: int, tokens: jax.Array, vocab: int) -> jax.Array:
+    """Teacher next-token logits for each of `tokens` -> (*tokens.shape,
+    vocab); row t is a pure function of (task_seed, t)."""
     key = jax.random.PRNGKey(task_seed)
-    logits = jax.random.normal(key, (vocab, vocab)) * concentration
-    return logits
+    row = lambda t: jax.random.normal(jax.random.fold_in(key, t), (vocab,))
+    flat = jax.vmap(row)(tokens.reshape(-1)) * CONCENTRATION
+    return flat.reshape(*tokens.shape, vocab)
 
 
-def sample_markov(key: jax.Array, table: jax.Array, batch: int, seq: int):
-    vocab = table.shape[0]
+def markov_table(vocab: int, task_seed: int):
+    """The whole (vocab, vocab) teacher; for inspection at small vocab."""
+    return markov_rows(task_seed, jnp.arange(vocab), vocab)
+
+
+def sample_markov(key: jax.Array, task_seed: int, vocab: int, batch: int,
+                  seq: int):
     k0, key = jax.random.split(key)
     first = jax.random.randint(k0, (batch,), 0, vocab)
 
     def step(tok, k):
-        nxt = jax.random.categorical(k, table[tok])
+        nxt = jax.random.categorical(k, markov_rows(task_seed, tok, vocab))
         return nxt, nxt
 
     _, rest = jax.lax.scan(step, first, jax.random.split(key, seq - 1))
@@ -49,10 +61,9 @@ class SyntheticLM:
     codebooks: int = 0
 
     def __post_init__(self):
-        self._table = markov_table(self.vocab, self.task_seed)
         self._sample = jax.jit(
-            lambda key: sample_markov(key, self._table, self.batch,
-                                      self.seq + 1))
+            lambda key: sample_markov(key, self.task_seed, self.vocab,
+                                      self.batch, self.seq + 1))
 
     def batch_at(self, step: int, shard: int = 0, num_shards: int = 1) -> Dict:
         """Batch for global `step`; `shard`/`num_shards` carve the global

@@ -38,6 +38,7 @@ from __future__ import annotations
 
 from typing import Dict, Optional, Tuple
 
+import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -334,3 +335,13 @@ def named(tree, specs, mesh: Mesh):
         seq = [named(None, v, mesh) for v in specs]
         return tuple(seq) if isinstance(specs, tuple) else seq
     return NamedSharding(mesh, specs)
+
+
+def init_placed(init_fn, rng, mesh: Mesh, specs_of):
+    """Run `init_fn(rng)` under jit with every output leaf placed where
+    `specs_of(shapes)` (a spec tree for the eval_shape'd output) puts it:
+    each device draws only its own shards, so no tree is materialised whole
+    on the first device before placement. Returns (tree, shardings)."""
+    shapes = jax.eval_shape(init_fn, rng)
+    sh = named(shapes, specs_of(shapes), mesh)
+    return jax.jit(init_fn, out_shardings=sh)(rng), sh

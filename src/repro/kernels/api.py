@@ -25,13 +25,18 @@ Backend selection replaces the old ad-hoc `_use_pallas` string dispatch with
 a capability model: each op declares `platforms`, an int32 phase bound
 (`max_dim`), and an optional config predicate (`requires`); `resolve_op`
 walks the requested policy's candidate chain and returns the first op whose
-`supports()` passes. The einsum reference is always the terminal candidate,
-so resolution degrades instead of failing (vocab-sized grids fall off the
-Pallas int32 bound onto einsum even when "interpret" was requested).
+`supports()` passes. The einsum reference is the terminal candidate: `auto`
+takes it off-TPU and, on TPU, only where the Pallas op's constraints reject
+the site (vocab-sized grids past the int32 phase bound, non-Fourier bases);
+"interpret" degrades the same way. An explicit "pallas" request never
+degrades: where a registered Pallas op cannot run, resolution raises
+`KernelUnavailableError` (ops with no Pallas registration at all keep their
+einsum math).
 
 `KernelPolicy` is the build-time snapshot: `Model.__post_init__` resolves
-every targeted (site, op) pair once, warns when an explicitly requested
-backend had to be downgraded, and renders the outcome via `explain()`.
+every targeted (site, op) pair once, raises (pallas) or warns (interpret)
+when an explicitly requested backend cannot be honoured, and renders the
+outcome via `explain()`.
 """
 from __future__ import annotations
 
@@ -43,8 +48,9 @@ OPS = ("deltaw", "factored_apply", "bank_apply", "paged_attention")
 BACKENDS = ("pallas", "interpret", "einsum")
 
 # candidate chain per requested policy; first supported op wins. "interpret"
-# is debug-only: never auto-selected, and "pallas"/"interpret" both degrade
-# to the einsum reference when the accelerated op's constraints fail.
+# is debug-only: never auto-selected, and degrades to the einsum reference
+# when the accelerated op's constraints fail; an explicit "pallas" whose
+# registered op fails its constraints raises instead (resolve_op).
 CANDIDATES: Dict[str, Tuple[str, ...]] = {
     "auto": ("pallas", "einsum"),
     "pallas": ("pallas", "einsum"),
@@ -209,9 +215,14 @@ def resolve_op(op: str, method, peft=None, d1: int = 0, d2: int = 0, *,
         cand = _OPS.get((op, m.name, b))
         if cand is None:
             continue
-        ok, _ = cand.supports(d1, d2, peft, platform)
+        ok, why = cand.supports(d1, d2, peft, platform)
         if ok:
             return cand
+        if requested == "pallas" and b == "pallas":
+            raise KernelUnavailableError(
+                f"kernel_backend='pallas' requested for ({op!r}, {m.name!r}) "
+                f"at {d1}x{d2} on {platform}, but the pallas op cannot run "
+                f"there: {why}")
     if missing_ok:
         return None
     raise KernelUnavailableError(
@@ -244,7 +255,11 @@ class KernelPolicy:
 
     @classmethod
     def build(cls, method, sites: Sequence, peft,
-              platform: Optional[str] = None) -> "KernelPolicy":
+              platform: Optional[str] = None,
+              attention: Optional[Tuple[int, int]] = None) -> "KernelPolicy":
+        """`attention=(n_heads, head_dim)` adds the model-side
+        `paged_attention` op (site "attention") for models that serve
+        from the paged KV cache."""
         m = _method_obj(method)
         ensure_method(m)
         requested = requested_backend(peft)
@@ -252,6 +267,20 @@ class KernelPolicy:
             raise ValueError(f"unknown kernel backend {requested!r}; one of "
                              f"{sorted(CANDIDATES)}")
         platform = platform or _platform()
+        first = CANDIDATES[requested][0]
+
+        def resolve(owner, site, d1, d2, op):
+            chosen = resolve_op(op, owner, peft, d1, d2, platform=platform,
+                                missing_ok=True)
+            note = ""
+            if chosen is None or chosen.backend != first:
+                cand = _OPS.get((op, owner.name, first))
+                why = (f"no {first} op registered" if cand is None
+                       else cand.supports(d1, d2, peft, platform)[1])
+                note = f"{first} unavailable: {why}"
+            return Resolution(site, d1, d2, op,
+                              chosen.backend if chosen else "", note)
+
         res = []
         if getattr(m, "has_site_params", True):
             targets = getattr(peft, "target_modules", ())
@@ -259,27 +288,22 @@ class KernelPolicy:
                 if s.name.split("/")[-1] not in targets:
                     continue
                 for op in ops_for(m):
-                    chosen = resolve_op(op, m, peft, s.d_in, s.d_out,
-                                        platform=platform, missing_ok=True)
-                    note = ""
-                    first = CANDIDATES[requested][0]
-                    if chosen is None or chosen.backend != first:
-                        cand = _OPS.get((op, m.name, first))
-                        why = (f"no {first} op registered" if cand is None
-                               else cand.supports(s.d_in, s.d_out, peft,
-                                                  platform)[1])
-                        note = f"{first} unavailable: {why}"
-                    res.append(Resolution(s.name, s.d_in, s.d_out, op,
-                                          chosen.backend if chosen else "",
-                                          note))
+                    res.append(resolve(m, s.name, s.d_in, s.d_out, op))
+        if attention is not None:
+            from repro.kernels import paged_attention
+            ensure_method(paged_attention.OWNER)
+            res.append(resolve(paged_attention.OWNER, "attention",
+                               *attention, "paged_attention"))
         policy = cls(m.name, requested, platform, tuple(res))
-        if requested in ("pallas", "interpret"):
-            # warn only where an op for the requested backend EXISTS but its
-            # constraints rejected it — ops with no accelerated registration
-            # (einsum-only math) fall through silently
+        if requested == "interpret":
+            # (an unhonourable explicit "pallas" already raised in
+            # resolve_op.) Warn only where an op for the requested backend
+            # EXISTS but its constraints rejected it — ops with no
+            # accelerated registration (einsum-only math) fall through
             missed = sorted({f"{r.op}@{r.site}" for r in res
                              if r.backend != requested
-                             and (r.op, m.name, requested) in _OPS})
+                             and any((r.op, o, requested) in _OPS
+                                     for o in (m.name, "attention"))})
             if missed:
                 warnings.warn(
                     f"kernel_backend={requested!r} requested but unavailable "
@@ -307,8 +331,8 @@ class KernelPolicy:
         """Human-readable per-site resolution report (examples print this)."""
         head = (f"kernel policy: method={self.method} "
                 f"requested={self.requested} platform={self.platform}")
-        if not self.resolutions:
-            return head + "\n  (no registered kernel ops for this method)"
+        if not any(r.op != "paged_attention" for r in self.resolutions):
+            head += "\n  (no registered kernel ops for this method)"
         lines = [head]
         for r in self.resolutions:
             line = (f"  {r.site} ({r.d1}x{r.d2}) {r.op} -> "

@@ -13,7 +13,9 @@ Vocab-sized grids route to the einsum reference via the op's `max_dim`.
 
 Backward (`dc`): same tiling over the cotangent g; per tile
     dc += Σ_k (gᵀ C1)[k,:] ⊙ C2[k,:]
-accumulated into one (n,) block across sequential grid steps.
+accumulated into the layer's (1, n) block across its sequential tile
+steps. Grid, block layout and matmul precision as in fourier_deltaw.py: a
+leading axis over the (L, n) layer stack.
 
 VMEM at (bm, bn, n) = (256, 256, 1024): basis blocks 2·256·1024·4B = 2 MB +
 0.25 MB tile accumulator — half the FourierFT kernel's footprint (no sin
@@ -26,6 +28,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import fourier_deltaw as _fk
 
 PI = 3.141592653589793
 
@@ -50,54 +55,53 @@ CAPS = {
 def _cos_block(idx0: jax.Array, size: int, dim: int, uv: jax.Array,
                c: jax.Array | None):
     """Half-integer-phase cosine block for rows [idx0, idx0+size) of a
-    `dim`-point DCT axis: cos(π(2j+1)u/2d), optionally pre-scaled by c."""
+    `dim`-point DCT axis: cos(π(2j+1)u/2d), optionally pre-scaled by c.
+    uv and c are (1, n) rows."""
     rows = jax.lax.broadcasted_iota(jnp.int32, (size, 1), 0) + idx0
-    prod = (2 * rows + 1) * uv[None, :].astype(jnp.int32)   # exact in int32
+    prod = (2 * rows + 1) * uv                              # exact in int32
     prod = jax.lax.rem(prod, jnp.int32(4 * dim))            # cos period: 4d
     cos = jnp.cos(prod.astype(jnp.float32) * (PI / (2.0 * dim)))
     if c is not None:
-        cos = cos * c[None, :]
+        cos = cos * c
     return cos
 
 
 def _deltaw_kernel(c_ref, u_ref, v_ref, o_ref, *, d1, d2, alpha, bm, bn):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
+    i = pl.program_id(1)
+    j = pl.program_id(2)
     cb = _cos_block(i * bm, bm, d1, u_ref[...], c_ref[...])
     rb = _cos_block(j * bn, bn, d2, v_ref[...], None)
-    acc = jax.lax.dot_general(cb, rb, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
+    acc = _fk._dot(cb, rb, ((1,), (1,)))
     o_ref[...] = acc * (alpha / (d1 * d2))
 
 
 def deltaw_pallas(c: jax.Array, u: jax.Array, v: jax.Array, d1: int, d2: int,
                   alpha: float, *, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
                   interpret: bool = False) -> jax.Array:
-    """c (n,) f32, u/v (n,) i32 (n padded to 128 | c zero-padded).
-    Returns ΔW (d1p, d2p) f32 with d1p/d2p the block-padded dims."""
-    n = c.shape[0]
+    """c (L, 1, npad) f32, u/v (1, npad) i32 (npad a multiple of 128; padded
+    columns carry c = 0). Returns the ΔW stack (L, d1p, d2p) f32 with
+    d1p/d2p the block-padded dims."""
+    L, _, npad = c.shape
     d1p = -(-d1 // bm) * bm
     d2p = -(-d2 // bn) * bn
-    grid = (d1p // bm, d2p // bn)
+    coef, entry, tile = _fk._specs(npad, bm, bn)
     kernel = functools.partial(_deltaw_kernel, d1=d1, d2=d2, alpha=alpha,
                                bm=bm, bn=bn)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n,), lambda i, j: (0,)),
-            pl.BlockSpec((n,), lambda i, j: (0,)),
-            pl.BlockSpec((n,), lambda i, j: (0,)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((d1p, d2p), jnp.float32),
+        grid=(L, d1p // bm, d2p // bn),
+        in_specs=[coef, entry, entry],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((L, d1p, d2p), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(c, u, v)
 
 
 def _dc_kernel(g_ref, u_ref, v_ref, o_ref, *, d1, d2, alpha, bm, bn):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
+    i = pl.program_id(1)
+    j = pl.program_id(2)
 
     @pl.when((i == 0) & (j == 0))
     def _init():
@@ -106,29 +110,27 @@ def _dc_kernel(g_ref, u_ref, v_ref, o_ref, *, d1, d2, alpha, bm, bn):
     g = g_ref[...].astype(jnp.float32)                    # (bm, bn)
     cb = _cos_block(i * bm, bm, d1, u_ref[...], None)
     rb = _cos_block(j * bn, bn, d2, v_ref[...], None)
-    a = jax.lax.dot_general(g, cb, (((0,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)   # (bn, n)
-    o_ref[...] += jnp.sum(a * rb, axis=0) * (alpha / (d1 * d2))
+    a = _fk._dot(g, cb, ((0,), (0,)))                     # (bn, n)
+    o_ref[...] += jnp.sum(a * rb, axis=0, keepdims=True) * (alpha / (d1 * d2))
 
 
 def dc_pallas(g: jax.Array, u: jax.Array, v: jax.Array, d1: int, d2: int,
               alpha: float, *, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
               interpret: bool = False) -> jax.Array:
-    """g (d1p, d2p) f32 cotangent (zero-padded outside (d1, d2)) -> dc (n,)."""
-    n = u.shape[0]
-    d1p, d2p = g.shape
-    grid = (d1p // bm, d2p // bn)
+    """g (L, d1p, d2p) f32 cotangent (zero-padded outside (d1, d2)) -> dc
+    (L, 1, npad), accumulated per layer over that layer's sequential tiles."""
+    L, d1p, d2p = g.shape
+    npad = u.shape[-1]
+    coef, entry, tile = _fk._specs(npad, bm, bn)
     kernel = functools.partial(_dc_kernel, d1=d1, d2=d2, alpha=alpha,
                                bm=bm, bn=bn)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((n,), lambda i, j: (0,)),
-            pl.BlockSpec((n,), lambda i, j: (0,)),
-        ],
-        out_specs=pl.BlockSpec((n,), lambda i, j: (0,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
+        grid=(L, d1p // bm, d2p // bn),
+        in_specs=[tile, entry, entry],
+        out_specs=coef,
+        out_shape=jax.ShapeDtypeStruct((L, 1, npad), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(g, u, v)

@@ -1,6 +1,7 @@
 """Pallas TPU kernels for FourierFT ΔW materialization and its VJP.
 
-Forward (`deltaw`): grid over (d1/bm, d2/bn) output tiles. Each tile builds its
+Forward (`deltaw`): grid over (L, d1/bm, d2/bn) — the layer stack, then the
+output tiles of that layer's ΔW. Each tile builds its
 cos/sin basis blocks *in VMEM* from integer phase arithmetic (no HBM-resident
 (d, n) basis — saves 4·(d1+d2)·n·4 bytes of HBM traffic per materialization)
 and accumulates two MXU matmuls:
@@ -16,8 +17,13 @@ grids; not a default adaptation target) to the einsum path.
 
 Backward (`dc`): same tiling over the incoming cotangent g; per tile
     dc += Σ_k cosφ[k,:] ⊙ (gᵀ cosθ)[k,:] − sinφ ⊙ (gᵀ sinθ)
-accumulated into a single (n,) output block across sequential grid steps
-(TPU grid order is sequential; interpret mode matches).
+accumulated into the layer's (1, n) output block across its sequential tile
+steps (TPU grid order is sequential; interpret mode matches).
+
+Both kernels take the stacked (L, n) coefficients directly, with a leading
+grid axis over the stack: a `jax.vmap` over `pallas_call` would hand the
+kernel a squeezed (n,) block of an (L, n) array, which the TPU's (8, 128)
+block rule refuses. Matmuls run at float32 precision (`Precision.HIGHEST`).
 
 VMEM at (bm, bn, n) = (256, 256, 1024): basis blocks 4·256·1024·4B = 4MB,
 tile accumulators 0.5MB — comfortably double-bufferable in 16MB VMEM.
@@ -29,6 +35,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 TWO_PI = 6.283185307179586
 
@@ -57,60 +64,72 @@ def _phase_block(idx0: jax.Array, size: int, dim: int, uv: jax.Array,
                  c: jax.Array | None):
     """cos/sin basis block for rows [idx0, idx0+size) of a `dim`-point axis.
 
-    uv: (n,) int32 spectral indices. Returns (cos (size,n), sin (size,n)),
-    optionally pre-scaled by c."""
+    uv: (1, n) int32 spectral indices. Returns (cos (size,n), sin (size,n)),
+    optionally pre-scaled by c (1, n)."""
     rows = jax.lax.broadcasted_iota(jnp.int32, (size, 1), 0) + idx0
-    prod = rows * uv[None, :].astype(jnp.int32)          # exact in int32
+    prod = rows * uv                                     # exact in int32
     prod = jax.lax.rem(prod, jnp.int32(dim))
     ang = prod.astype(jnp.float32) * (TWO_PI / dim)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     if c is not None:
-        cos = cos * c[None, :]
-        sin = sin * c[None, :]
+        cos = cos * c
+        sin = sin * c
     return cos, sin
 
 
-def _deltaw_kernel(c_ref, u_ref, v_ref, o_ref, *, d1, d2, alpha, bm, bn):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    c = c_ref[...]
-    ct, st = _phase_block(i * bm, bm, d1, u_ref[...], c)
-    cp, sp = _phase_block(j * bn, bn, d2, v_ref[...], None)
-    acc = jax.lax.dot_general(ct, cp, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    acc -= jax.lax.dot_general(st, sp, (((1,), (1,)), ((), ())),
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
+
+
+def _deltaw_kernel(c_ref, u_ref, v_ref, o_ref, *, d1, d2, alpha, bm, bn):
+    i = pl.program_id(1)
+    j = pl.program_id(2)
+    ct, st = _phase_block(i * bm, bm, d1, u_ref[...], c_ref[...])
+    cp, sp = _phase_block(j * bn, bn, d2, v_ref[...], None)
+    acc = _dot(ct, cp, ((1,), (1,))) - _dot(st, sp, ((1,), (1,)))
     o_ref[...] = acc * (alpha / (d1 * d2))
+
+
+def _specs(npad: int, bm: int, bn: int):
+    """Block specs over the (L, d1p/bm, d2p/bn) grid: one layer's (1, npad)
+    coefficient row and the shared (1, npad) entry rows per step — their
+    last two dims equal the arrays', as the TPU (8, 128) block rule asks —
+    and the layer's (bm, bn) tile of the (L, d1p, d2p) ΔW stack."""
+    coef = pl.BlockSpec((None, 1, npad), lambda l, i, j: (l, 0, 0))
+    entry = pl.BlockSpec((1, npad), lambda l, i, j: (0, 0))
+    tile = pl.BlockSpec((None, bm, bn), lambda l, i, j: (l, i, j))
+    return coef, entry, tile
 
 
 def deltaw_pallas(c: jax.Array, u: jax.Array, v: jax.Array, d1: int, d2: int,
                   alpha: float, *, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
                   interpret: bool = False) -> jax.Array:
-    """c (n,) f32, u/v (n,) i32 (n padded to 128 | c zero-padded).
-    Returns ΔW (d1p, d2p) f32 with d1p/d2p the block-padded dims."""
-    n = c.shape[0]
+    """c (L, 1, npad) f32, u/v (1, npad) i32 (npad a multiple of 128; padded
+    columns carry c = 0). Returns the ΔW stack (L, d1p, d2p) f32 with
+    d1p/d2p the block-padded dims."""
+    L, _, npad = c.shape
     d1p = -(-d1 // bm) * bm
     d2p = -(-d2 // bn) * bn
-    grid = (d1p // bm, d2p // bn)
+    coef, entry, tile = _specs(npad, bm, bn)
     kernel = functools.partial(_deltaw_kernel, d1=d1, d2=d2, alpha=alpha,
                                bm=bm, bn=bn)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((n,), lambda i, j: (0,)),
-            pl.BlockSpec((n,), lambda i, j: (0,)),
-            pl.BlockSpec((n,), lambda i, j: (0,)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((d1p, d2p), jnp.float32),
+        grid=(L, d1p // bm, d2p // bn),
+        in_specs=[coef, entry, entry],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((L, d1p, d2p), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(c, u, v)
 
 
 def _dc_kernel(g_ref, u_ref, v_ref, o_ref, *, d1, d2, alpha, bm, bn):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
+    i = pl.program_id(1)
+    j = pl.program_id(2)
 
     @pl.when((i == 0) & (j == 0))
     def _init():
@@ -119,32 +138,29 @@ def _dc_kernel(g_ref, u_ref, v_ref, o_ref, *, d1, d2, alpha, bm, bn):
     g = g_ref[...].astype(jnp.float32)                    # (bm, bn)
     ct, st = _phase_block(i * bm, bm, d1, u_ref[...], None)
     cp, sp = _phase_block(j * bn, bn, d2, v_ref[...], None)
-    a = jax.lax.dot_general(g, ct, (((0,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (bn, n)
-    b = jax.lax.dot_general(g, st, (((0,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    contrib = jnp.sum(a * cp - b * sp, axis=0) * (alpha / (d1 * d2))
-    o_ref[...] += contrib
+    a = _dot(g, ct, ((0,), (0,)))                         # (bn, n)
+    b = _dot(g, st, ((0,), (0,)))
+    contrib = jnp.sum(a * cp - b * sp, axis=0, keepdims=True)
+    o_ref[...] += contrib * (alpha / (d1 * d2))
 
 
 def dc_pallas(g: jax.Array, u: jax.Array, v: jax.Array, d1: int, d2: int,
               alpha: float, *, bm: int = DEFAULT_BM, bn: int = DEFAULT_BN,
               interpret: bool = False) -> jax.Array:
-    """g (d1p, d2p) f32 cotangent (zero-padded outside (d1, d2)) -> dc (n,)."""
-    n = u.shape[0]
-    d1p, d2p = g.shape
-    grid = (d1p // bm, d2p // bn)
+    """g (L, d1p, d2p) f32 cotangent (zero-padded outside (d1, d2)) -> dc
+    (L, 1, npad), accumulated per layer over that layer's sequential tiles."""
+    L, d1p, d2p = g.shape
+    npad = u.shape[-1]
+    coef, entry, tile = _specs(npad, bm, bn)
     kernel = functools.partial(_dc_kernel, d1=d1, d2=d2, alpha=alpha,
                                bm=bm, bn=bn)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((n,), lambda i, j: (0,)),
-            pl.BlockSpec((n,), lambda i, j: (0,)),
-        ],
-        out_specs=pl.BlockSpec((n,), lambda i, j: (0,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
+        grid=(L, d1p // bm, d2p // bn),
+        in_specs=[tile, entry, entry],
+        out_specs=coef,
+        out_shape=jax.ShapeDtypeStruct((L, 1, npad), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(g, u, v)

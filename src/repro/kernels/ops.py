@@ -4,8 +4,9 @@ non-Pallas accelerated paths, consumed by the kernel registry (api.py).
 `make_deltaw_harness(fwd, bwd, bm, bn)` packages the custom-VJP + padding
 plumbing once — n padded to the 128-lane boundary (entries padded directly;
 padded columns carry c = 0 so they contribute nothing), output sliced back to
-(d1, d2), cotangents zero-padded to the backward kernel's block grid, stacked
-(L, n) coefficients vmapped — and is instantiated for both the FourierFT
+(d1, d2), cotangents zero-padded to the backward kernel's block grid, and
+the (L, n) coefficient stack handed to the kernels whole (a single (n,)
+vector is a stack of one) — and is instantiated for both the FourierFT
 kernels (fourier_deltaw.py) and the DCT kernels (dct_deltaw.py).
 
 `circulant_apply_fft` is the circulant adapter's fast apply: x @ C is a
@@ -20,9 +21,11 @@ dispatches through the registry like the adapter stack does.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import dct_deltaw as _dk
 from repro.kernels import fourier_deltaw as _fk
@@ -46,23 +49,33 @@ def _pad_entries(entries: jax.Array) -> jax.Array:
     return jnp.pad(entries, ((0, 0), (0, npad - n)))
 
 
-def _pad_c(c: jax.Array, npad: int) -> jax.Array:
-    """Zero-pad (n,) coefficients to npad — padded basis columns are then
-    scaled by 0 and drop out of the tile matmuls exactly."""
-    n = c.shape[-1]
-    if npad == n:
-        return c
-    return jnp.pad(c, (0, npad - n))
+def _over_stack(kernel, stack: int):
+    """Run `kernel(stacked, *shared)` — stacked leading (L, ...) operand in,
+    (L, ...) result out — so it can sit inside a sharded program. A Mosaic
+    kernel cannot be partitioned automatically, so under a mesh (set with
+    `jax.set_mesh` / `jax.sharding.use_abstract_mesh` by the launch layer)
+    it runs in a `shard_map` that splits the layer stack over the mesh
+    axes when their size divides L, and replicates it otherwise. Without a
+    mesh the kernel is called as is."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return kernel
+    axes = tuple(a for a in mesh.axis_names if mesh.shape[a] > 1)
+    split = P(axes) if stack % math.prod(mesh.shape[a] for a in axes) == 0 \
+        else P()
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(split, P(), P()),
+                         out_specs=split, check_vma=False)
 
 
 def make_deltaw_harness(fwd_kernel, bwd_kernel, bm: int, bn: int):
     """Reusable custom-VJP + padding wrapper for (c, entries) -> ΔW spectral
     kernels.
 
-    fwd_kernel(c, u, v, d1, d2, alpha, interpret=) -> (d1p, d2p) tile-padded
-    ΔW; bwd_kernel(g, u, v, d1, d2, alpha, interpret=) -> (npad,) dc. The
-    returned callable is `h(c, entries, d1, d2, alpha, *, interpret=False)`
-    accepting c as (n,) or stacked (L, n)."""
+    fwd_kernel(c (L,1,npad), u, v (1,npad), d1, d2, alpha, interpret=) ->
+    (L, d1p, d2p) tile-padded ΔW stack; bwd_kernel(g (L,d1p,d2p), u, v, d1,
+    d2, alpha, interpret=) -> (L, 1, npad) dc. The returned callable is
+    `h(c, entries, d1, d2, alpha, *, interpret=False)` accepting c as (n,)
+    or stacked (L, n); the stack is a grid axis of the kernels."""
 
     @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
     def _deltaw(c, entries, d1, d2, alpha, interpret):
@@ -70,26 +83,32 @@ def make_deltaw_harness(fwd_kernel, bwd_kernel, bm: int, bn: int):
 
     def _fwd(c, entries, d1, d2, alpha, interpret):
         ep = _pad_entries(entries)
-        cp = _pad_c(c, ep.shape[1])
-        out = fwd_kernel(cp, ep[0], ep[1], d1, d2, alpha, interpret=interpret)
-        return out[:d1, :d2], (entries,)
+        n, npad = c.shape[1], ep.shape[1]
+        cp = jnp.pad(c, ((0, 0), (0, npad - n)))[:, None, :]
+        fwd = lambda cc, u, v: fwd_kernel(cc, u, v, d1, d2, alpha,
+                                          interpret=interpret)
+        out = _over_stack(fwd, c.shape[0])(cp, ep[:1], ep[1:])
+        return out[:, :d1, :d2], (entries,)
 
     def _bwd(d1, d2, alpha, interpret, res, g):
         (entries,) = res
         n = entries.shape[1]
         ep = _pad_entries(entries)
         d1p, d2p = -(-d1 // bm) * bm, -(-d2 // bn) * bn
-        gp = jnp.pad(g.astype(jnp.float32), ((0, d1p - d1), (0, d2p - d2)))
-        dc = bwd_kernel(gp, ep[0], ep[1], d1, d2, alpha, interpret=interpret)
-        return (dc[:n], None)
+        gp = jnp.pad(g.astype(jnp.float32),
+                     ((0, 0), (0, d1p - d1), (0, d2p - d2)))
+        bwd = lambda gg, u, v: bwd_kernel(gg, u, v, d1, d2, alpha,
+                                          interpret=interpret)
+        dc = _over_stack(bwd, g.shape[0])(gp, ep[:1], ep[1:])
+        return (dc[:, 0, :n], None)
 
     _deltaw.defvjp(_fwd, _bwd)
 
     def harness(c: jax.Array, entries: jax.Array, d1: int, d2: int,
                 alpha: float, *, interpret: bool = False) -> jax.Array:
-        fn = lambda cc: _deltaw(cc.astype(jnp.float32), entries, d1, d2,
-                                alpha, interpret)
-        return jax.vmap(fn)(c) if c.ndim == 2 else fn(c)
+        stack = c.astype(jnp.float32).reshape(-1, c.shape[-1])
+        out = _deltaw(stack, entries, d1, d2, alpha, interpret)
+        return out if c.ndim == 2 else out[0]
 
     return harness
 

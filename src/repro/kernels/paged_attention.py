@@ -58,18 +58,21 @@ from repro.kernels.api import KernelOp
 from repro.models import attention as attn_mod
 
 NEG_INF = attn_mod.NEG_INF
+# float32 matmuls in the kernel: Mosaic's default contracts f32 operands in
+# one bf16 pass (~4e-3 relative error against the float32 reference)
+_F32 = jax.lax.Precision.HIGHEST
 
 # Capability metadata for the repro.analysis kernel verifier (DESIGN.md
 # §Analysis): the declared online-softmax scratch layout, checked against
 # the canonical derivation (running max/denom are one f32 per (kv_head,
-# group, window-row) triple; the accumulator adds the head dim), plus
+# window-row x group) pair; the accumulator adds the head dim), plus
 # reference dims for the VMEM-footprint check. Must match the
 # `scratch_shapes` passed to pallas_call below — the verifier exists so
 # a retile can't change one without the other.
 CAPS = {
     "kind": "paged_attention",
-    "scratch": {"m": ("K", "G", "W"), "l": ("K", "G", "W"),
-                "acc": ("K", "G", "W", "dh")},
+    "scratch": {"m": ("K", "W*G"), "l": ("K", "W*G"),
+                "acc": ("K", "W*G", "dh")},
     "ref": {"K": 8, "G": 4, "W": 8, "dh": 128, "ps": 16},
 }
 
@@ -101,7 +104,7 @@ def paged_attention_einsum(q, k_pages, v_pages, block_table, kv_len):
 # ---------------------------------------------------------------------------
 
 def _paged_attn_kernel(bt_ref, kvlen_ref, q_ref, k_ref, v_ref, o_ref,
-                       m_ref, l_ref, acc_ref, *, page_size):
+                       m_ref, l_ref, acc_ref, *, page_size, group):
     b = pl.program_id(0)
     p = pl.program_id(1)
 
@@ -111,20 +114,20 @@ def _paged_attn_kernel(bt_ref, kvlen_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0]                                   # (W, H, dh)
+    q = q_ref[0]                                   # (K, R, dh), r = w*G + g
     k = k_ref[0]                                   # (ps, K, dh)
     v = v_ref[0]
-    W, H, dh = q.shape
-    K = k.shape[1]
-    G = H // K
-    qs = q.reshape(W, K, G, dh).astype(jnp.float32) * (dh ** -0.5)
-    s = jnp.einsum("wkgd,tkd->kgwt", qs, k.astype(jnp.float32))
+    R, dh = q.shape[1], q.shape[2]
+    qs = q.astype(jnp.float32) * (dh ** -0.5)
+    s = jnp.einsum("krd,tkd->krt", qs, k.astype(jnp.float32),
+                   precision=_F32)
     cols = p * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, 1, page_size), 3)
-    # query row j of the window sees kv_len + j columns (causal inside the
-    # window, ragged across slots; W == 1 is the plain decode mask)
+        jnp.int32, (1, 1, page_size), 2)
+    # query row r = w*G + g sits at window position w and sees kv_len + w
+    # columns (causal inside the window, ragged across slots; W == 1 is the
+    # plain decode mask)
     lim = kvlen_ref[b] + jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, W, 1), 2)
+        jnp.int32, (1, R, 1), 1) // group
     valid = cols < lim
     s = jnp.where(valid, s, NEG_INF)
     m_prev = m_ref[...]
@@ -135,28 +138,35 @@ def _paged_attn_kernel(bt_ref, kvlen_ref, q_ref, k_ref, v_ref, o_ref,
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + pexp.sum(axis=-1)
     acc_ref[...] = (acc_ref[...] * corr[..., None]
-                    + jnp.einsum("kgwt,tkd->kgwd", pexp,
-                                 v.astype(jnp.float32)))
+                    + jnp.einsum("krt,tkd->krd", pexp,
+                                 v.astype(jnp.float32), precision=_F32))
     m_ref[...] = m_new
 
     @pl.when(p == pl.num_programs(1) - 1)
     def _done():
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[..., None]
-        o_ref[...] = jnp.moveaxis(out, 2, 0).reshape(
-            1, W, H, dh).astype(o_ref.dtype)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 def paged_attention_pallas(q, k_pages, v_pages, block_table, kv_len, *,
                            interpret: bool = False):
+    """The window's query rows are regrouped outside the kernel into one
+    (K, W*G, dh) block per slot — row w*G + g is window position w, group
+    member g of KV head k — so the kernel runs 3-D batched matmuls over
+    the KV heads with no in-kernel reshape (Mosaic cannot split a minor
+    dim of W*G into (W, G) for W > 1)."""
     B, W, H, dh = q.shape
     _, ps, K, _ = k_pages.shape
     PPS = block_table.shape[1]
     G = H // K
+    R = W * G
+    qr = q.reshape(B, W, K, G, dh).transpose(0, 2, 1, 3, 4).reshape(
+        B, K, R, dh)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                     # block_table, kv_len
         grid=(B, PPS),
         in_specs=[
-            pl.BlockSpec((1, W, H, dh), lambda b, p, bt, kl: (b, 0, 0, 0)),
+            pl.BlockSpec((1, K, R, dh), lambda b, p, bt, kl: (b, 0, 0, 0)),
             # the gather: each (b, p) grid step pulls the ONE physical page
             # the block table names for slot b's logical page p
             pl.BlockSpec((1, ps, K, dh),
@@ -164,20 +174,22 @@ def paged_attention_pallas(q, k_pages, v_pages, block_table, kv_len, *,
             pl.BlockSpec((1, ps, K, dh),
                          lambda b, p, bt, kl: (bt[b, p], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, W, H, dh),
+        out_specs=pl.BlockSpec((1, K, R, dh),
                                lambda b, p, bt, kl: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((K, G, W), jnp.float32),     # running max
-            pltpu.VMEM((K, G, W), jnp.float32),     # running denom
-            pltpu.VMEM((K, G, W, dh), jnp.float32),  # running accumulator
+            pltpu.VMEM((K, R), jnp.float32),        # running max
+            pltpu.VMEM((K, R), jnp.float32),        # running denom
+            pltpu.VMEM((K, R, dh), jnp.float32),    # running accumulator
         ],
     )
-    return pl.pallas_call(
-        functools.partial(_paged_attn_kernel, page_size=ps),
-        out_shape=jax.ShapeDtypeStruct((B, W, H, dh), v_pages.dtype),
+    out = pl.pallas_call(
+        functools.partial(_paged_attn_kernel, page_size=ps, group=G),
+        out_shape=jax.ShapeDtypeStruct((B, K, R, dh), v_pages.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(block_table, kv_len, q, k_pages, v_pages)
+    )(block_table, kv_len, qr, k_pages, v_pages)
+    return out.reshape(B, K, W, G, dh).transpose(0, 2, 1, 3, 4).reshape(
+        B, W, H, dh)
 
 
 # ---------------------------------------------------------------------------

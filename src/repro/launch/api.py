@@ -30,6 +30,7 @@ import jax
 
 import repro.configs as configs
 from repro.configs.base import PEFTConfig
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import build
 
@@ -129,10 +130,21 @@ def build_scheduler(args):
         TieringConfig,
     )
 
+    from repro.configs.base import ShapeConfig
+    from repro.dist import plan as plan_mod
+    from repro.dist import sharding as shd
+
     cfg = _model_cfg(args)
     model = build(cfg, PEFTConfig(method="none"))
-    params = model.init(jax.random.PRNGKey(args.seed))
     mesh = make_host_mesh(model=args.model_parallel)
+    # weights are drawn under jit straight into the plan's placements
+    plan = plan_mod.resolve(
+        args.sharding_plan, model=model, mesh=mesh,
+        shape=ShapeConfig("serve", args.max_len, args.slots, "decode"),
+        workload="decode")
+    params, _ = shd.init_placed(
+        model.init, jax.random.PRNGKey(args.seed), mesh,
+        lambda t: plan.state_specs(t, mesh, cfg, False))
 
     bank, tenant_ids = None, []
     if args.bank_dir:
@@ -154,8 +166,7 @@ def build_scheduler(args):
                 print(f"skipping tenant {tid!r}: {e}")
 
     engine = Engine(model, params, batch_slots=args.slots,
-                    max_len=args.max_len, mesh=mesh, bank=bank,
-                    plan=args.sharding_plan)
+                    max_len=args.max_len, mesh=mesh, bank=bank, plan=plan)
     drafter = None
     if args.speculative:
         drafter = (SelfDrafter(k=args.draft_k) if args.drafter == "self"
@@ -222,6 +233,7 @@ def main(argv=None) -> None:
                     help="write two synthetic tenants for the model flags "
                          "into DIR and exit (no server)")
     args = ap.parse_args(argv)
+    setup_compile_cache()
     if args.export_demo_bank:
         export_demo_bank(args, args.export_demo_bank)
         return

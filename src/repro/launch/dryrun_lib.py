@@ -281,8 +281,6 @@ def analyze(cell: Cell, lowered, compiled, mesh: Mesh,
             compile_seconds: float) -> Dict:
     chips = mesh.devices.size
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):     # jax ≤ 0.4.x returns [dict]
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     # NOTE: XLA's cost_analysis visits while bodies once (no trip-count
     # scaling) -- useless for scanned programs. We re-derive from the HLO

@@ -1,35 +1,23 @@
 """Mesh construction — the ONE shared path for every launcher (train, serve,
 dryrun, tests). Functions (not module-level constants) so importing never
 touches jax device state; the dry-run sets XLA_FLAGS for 512 host devices
-before any jax import.
-
-Version compat: `axis_types=(AxisType.Auto, …)` keeps GSPMD auto-propagation
-explicit on new jax; jax ≤ 0.4.x predates the kwarg (Auto is the only
-behavior), so we pass it only when the installed jax supports it.
+before any jax import. Every axis is `AxisType.Auto`: GSPMD propagates
+shardings from the placements the plan sources pin.
 """
 from __future__ import annotations
 
-from typing import Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
-def _axis_type_kwargs(n: int) -> dict:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    try:
-        import inspect
-        if "axis_types" not in inspect.signature(jax.make_mesh).parameters:
-            return {}
-    except (TypeError, ValueError):
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
-def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> jax.sharding.Mesh:
-    return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(tuple(axes))))
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None) -> jax.sharding.Mesh:
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -47,11 +35,12 @@ def parse_mesh_shape(spec: str) -> Tuple[Tuple[int, ...], Tuple[str, ...]]:
     return dims, axes
 
 
-def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
-    """Small mesh over whatever devices exist (tests / local runs)."""
-    n = jax.device_count()
+def make_host_mesh(model: int = 1,
+                   devices: Optional[Sequence] = None) -> jax.sharding.Mesh:
+    """(data, model) mesh over `devices` (default: every local device)."""
+    n = len(devices) if devices is not None else jax.device_count()
     if n % model:
         raise ValueError(f"model parallelism {model} does not divide "
                          f"device count {n}")
     data = n // model
-    return make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"), devices=devices)

@@ -40,6 +40,8 @@ from repro.checkpoint import adapters as adapter_ckpt
 from repro.checkpoint import manager as ckpt
 from repro.configs.base import PEFTConfig
 from repro.core import adapter as adapter_api
+from repro.dist import sharding as shd
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import build
 from repro.serve import AdapterBank, Engine
@@ -104,6 +106,7 @@ def main(argv=None):
                          "from (dist/plan.py); search runs the planner once "
                          "at startup")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     cfg = configs.get(args.arch)
     if args.reduced:
@@ -115,7 +118,12 @@ def main(argv=None):
     else:
         peft = PEFTConfig(method=args.method, n=args.n, alpha=args.alpha)
     model = build(cfg, peft)
-    params = model.init(jax.random.PRNGKey(args.seed))
+    mesh = make_host_mesh(model=args.model_parallel)
+    # weights are drawn under jit straight into their mesh placements (the
+    # Engine re-places the merged tree per its own plan)
+    params, _ = shd.init_placed(
+        model.init, jax.random.PRNGKey(args.seed), mesh,
+        lambda t: shd.state_specs(t, mesh, cfg))
     if args.adapters:
         state, at = ckpt.restore(args.adapters)
         trainable = state["trainable"]
@@ -123,7 +131,6 @@ def main(argv=None):
             .split_params(model, params)
         params = join_params(model, trainable, frozen)
         print(f"loaded adapters from step {at}")
-    mesh = make_host_mesh(model=args.model_parallel)
 
     bank = None
     tenant_ids = []

@@ -21,6 +21,7 @@ from repro.configs.base import PEFTConfig, ShapeConfig, TrainConfig
 from repro.core import adapter as adapter_api
 from repro.data import SyntheticLM
 from repro.dist import plan as plan_mod
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import build
 from repro.train import loop, step as train_step
@@ -62,6 +63,7 @@ def main(argv=None):
                          "from (dist/plan.py); search runs the planner once "
                          "at startup")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     cfg = configs.get(args.arch)
     if args.reduced:
@@ -79,8 +81,6 @@ def main(argv=None):
     print(f"arch={cfg.name} method={args.method} "
           f"mesh={'x'.join(map(str, mesh.devices.shape))} "
           f"trainable={model.trainable_params():,}")
-    state, frozen = train_step.init_state(model, tcfg,
-                                          jax.random.PRNGKey(args.seed))
     fsdp = args.fsdp                       # None = auto
     data = SyntheticLM(vocab=cfg.vocab, batch=args.batch, seq=args.seq,
                        seed=args.seed, task_seed=args.task_seed,
@@ -91,6 +91,9 @@ def main(argv=None):
         workload="train")
     if plan_src.kind != "rules":
         print(f"sharding plan: {plan_src.describe()}")
+    state, frozen = train_step.init_state(model, tcfg,
+                                          jax.random.PRNGKey(args.seed),
+                                          mesh=mesh, fsdp=fsdp, plan=plan_src)
     state, frozen, state_sh, frozen_sh = train_step.shard_train_state(
         model, state, frozen, mesh, fsdp=fsdp, plan=plan_src)
     step_fn, batch_sh = train_step.make_sharded_train_step(

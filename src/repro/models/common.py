@@ -19,7 +19,9 @@ def head_rms_norm(x: jax.Array, w: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 
 def dense_init(rng: jax.Array, shape, dtype, scale: float = 0.02) -> jax.Array:
-    return (jax.random.normal(rng, shape, jnp.float32) * scale).astype(dtype)
+    """Normal(0, scale²) drawn directly in `dtype`: a bf16 weight never has
+    a float32 copy."""
+    return jax.random.normal(rng, shape, dtype) * jnp.asarray(scale, dtype)
 
 
 # ---------------------------------------------------------------------------

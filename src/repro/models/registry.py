@@ -105,7 +105,9 @@ class Model:
         # (DESIGN.md §Kernels) — an unknown kernel_backend fails at build,
         # and explain_kernels() reports what each hot path will run
         self.kernel_policy = kernel_api.KernelPolicy.build(
-            self.method, self.sites, self.peft)
+            self.method, self.sites, self.peft,
+            attention=((self.cfg.n_heads, self.cfg.head_dim)
+                       if self.supports_slot_cache else None))
 
     def _bank_kwargs(self, params: Dict) -> Dict:
         if self.bank_profiles is None:

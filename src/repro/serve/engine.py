@@ -27,6 +27,7 @@ in-flight prefill — lives in repro.serve.scheduler (DESIGN.md §Scheduler).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from collections import OrderedDict
 from dataclasses import dataclass
@@ -361,7 +362,11 @@ class Engine:
                  max_len: int, merge: bool = True, mesh=None,
                  bank: Optional[AdapterBank] = None, plan=None):
         if merge:
-            model, params = merge_for_serving(model, params)
+            # under the mesh, the Pallas ΔW kernels run per shard
+            # (kernels/ops.py); without one they run as is
+            with (jax.set_mesh(mesh) if mesh is not None
+                  else contextlib.nullcontext()):
+                model, params = merge_for_serving(model, params)
         self.bank = bank
         if bank is not None:
             # fresh Model facade: never mutate the caller's (merge may have

@@ -29,6 +29,7 @@ from __future__ import annotations
 import asyncio
 import queue as _queue
 import threading
+import traceback
 from typing import Callable, Dict, List, Optional
 
 from repro.serve.engine import Request
@@ -203,7 +204,9 @@ class SchedulerBridge:
             except Exception as e:             # noqa: BLE001 — fail streams
                 # a poisoned admission (e.g. corrupt checkpoint at load)
                 # surfaces here; every live stream gets the error rather
-                # than hanging, and the pump keeps serving
+                # than hanging, and the pump keeps serving — with the
+                # cause on stderr, since no stream shows the traceback
+                traceback.print_exc()
                 for rid, handle in list(self._handles.items()):
                     self._post(handle, ("error", f"scheduler error: {e}"))
                     try:

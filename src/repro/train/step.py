@@ -62,22 +62,39 @@ def join_params(model: Model, trainable: Dict, frozen: Dict) -> Dict:
     return {"base": base, "peft": peft_tree}
 
 
-def init_state(model: Model, tcfg: TrainConfig, rng: jax.Array) -> Tuple[Dict, Dict]:
+def init_state(model: Model, tcfg: TrainConfig, rng: jax.Array, mesh=None,
+               fsdp: bool = None, plan=None) -> Tuple[Dict, Dict]:
     """-> (state, frozen). state = {step, trainable, opt, loss_ema, anomalies}
-    (+ ef_residual when int8 error-feedback grad compression is on)."""
-    params = model.init(rng)
-    trainable, frozen = split_params(model, params)
-    state = {
-        "step": jnp.zeros((), jnp.int32),
-        "trainable": trainable,
-        "opt": adamw.init(trainable),
-        "loss_ema": jnp.zeros((), jnp.float32),
-        "anomalies": jnp.zeros((), jnp.int32),
-    }
-    if tcfg.grad_compression == "int8_ef":
-        from repro.dist import compression
-        state["ef_residual"] = compression.init_residual(trainable)
-    return state, frozen
+    (+ ef_residual when int8 error-feedback grad compression is on).
+
+    With `mesh`, the pair is built under jit straight into the placements
+    `shard_train_state` gives it (same `fsdp`/`plan`), so no device ever
+    holds the whole tree on the way there."""
+    def build(rng):
+        params = model.init(rng)
+        trainable, frozen = split_params(model, params)
+        state = {
+            "step": jnp.zeros((), jnp.int32),
+            "trainable": trainable,
+            "opt": adamw.init(trainable),
+            "loss_ema": jnp.zeros((), jnp.float32),
+            "anomalies": jnp.zeros((), jnp.int32),
+        }
+        if tcfg.grad_compression == "int8_ef":
+            from repro.dist import compression
+            state["ef_residual"] = compression.init_residual(trainable)
+        return state, frozen
+
+    if mesh is None:
+        return build(rng)
+    from repro.dist import sharding as shd
+    src = _plan_source(plan)
+    if fsdp is None:
+        fsdp = shd.fsdp_default(model.cfg, mesh)
+    specs = lambda t: src.state_specs(t, mesh, model.cfg, fsdp)
+    pair, _ = shd.init_placed(build, rng, mesh,
+                              lambda t: (specs(t[0]), specs(t[1])))
+    return pair
 
 
 def _loss_for(model: Model):
@@ -205,6 +222,8 @@ def make_sharded_train_step(model: Model, tcfg: TrainConfig, mesh,
     absent). Returns (jitted_step, batch_sharding) — feed batches through
     `jax.device_put(batch, batch_sharding)` (train/loop.py does this when
     given `batch_sharding`)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
     from repro.configs.base import ShapeConfig
     from repro.dist import sharding as shd
     src = _plan_source(plan)
@@ -225,6 +244,15 @@ def make_sharded_train_step(model: Model, tcfg: TrainConfig, mesh,
     b_sh = shd.named(batch_example,
                      src.batch_specs(batch_example, mesh, shape), mesh)
     step = make_train_step(model, tcfg)
-    jitted = jax.jit(step, in_shardings=(st_sh, fr_sh, b_sh),
+
+    def step_on_mesh(state, frozen, batch):
+        # the mesh is visible while tracing, so Pallas kernels (which XLA
+        # cannot partition) run per shard (kernels/ops.py)
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return step(state, frozen, batch)
+
+    # the state leaves as it came in (a donated buffer keeps its placement)
+    jitted = jax.jit(step_on_mesh, in_shardings=(st_sh, fr_sh, b_sh),
+                     out_shardings=(st_sh, NamedSharding(mesh, P())),
                      donate_argnums=(0,))
     return jitted, b_sh

@@ -134,7 +134,7 @@ import jax, numpy as np
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import manager as ckpt
-from repro.launch.mesh import make_mesh   # version-guarded axis_types
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((%(ndev)d,), ("model",))
 w = jnp.arange(64.0).reshape(8, 8)
 sharded = jax.device_put(w, NamedSharding(mesh, P(None, "model")))

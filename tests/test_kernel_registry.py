@@ -203,25 +203,32 @@ class TestKernelPolicy:
         pol = model.kernel_policy.validate()
         assert pol.method == "fourierft" and pol.requested == "interpret"
         assert {r.op for r in pol.resolutions} == {"deltaw", "factored_apply",
-                                                   "bank_apply"}
+                                                   "bank_apply",
+                                                   "paged_attention"}
         assert pol.backend_for("layers/wq", "deltaw") == "interpret"
+        assert pol.backend_for("attention", "paged_attention") == "interpret"
         text = model.explain_kernels()
         assert "layers/wq" in text and "deltaw -> interpret" in text
+        assert "paged_attention -> interpret" in text
 
     def test_explicit_pallas_downgrade_warns(self):
+        """An explicit pallas request that cannot be honoured off-TPU is an
+        error at model build, never a silent (or merely warned) einsum
+        downgrade."""
         if jax.default_backend() == "tpu":
             pytest.skip("no downgrade on TPU")
         cfg = C.reduced(C.get("yi-6b")).replace(vocab=64)
-        with pytest.warns(UserWarning, match="pallas.*unavailable"):
-            model = build(cfg, _peft("fourierft", "pallas"))
-        assert model.kernel_policy.backend_for("layers/wq",
-                                               "deltaw") == "einsum"
+        with pytest.raises(api.KernelUnavailableError,
+                           match="pallas.*cannot run"):
+            build(cfg, _peft("fourierft", "pallas"))
 
     def test_stateless_methods_have_empty_policy(self):
         cfg = C.reduced(C.get("yi-6b")).replace(vocab=64)
         for name in ("none", "full"):
             model = build(cfg, PEFTConfig(method=name))
-            assert model.kernel_policy.resolutions == ()
+            # only the model-side paged_attention op: no adapter site ops
+            assert [r.op for r in model.kernel_policy.resolutions] \
+                == ["paged_attention"]
             assert "no registered kernel ops" in model.explain_kernels()
 
 

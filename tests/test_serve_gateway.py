@@ -333,6 +333,17 @@ class TestGatewayHTTP:
                 b = asyncio.ensure_future(_post(
                     host, port, "/v1/completions",
                     _completion("base", [4, 5], 24, stream=False)))
+                # probe only once a holds the slot and b waits in the queue:
+                # a probe that reached the server before b would take the
+                # queue place itself and leave the 429 to b
+                sched = server.sched
+                deadline = asyncio.get_event_loop().time() + 10.0
+                while not await server.bridge.call(
+                        lambda: len(sched.queue) >= 1
+                        and sched.slots.any_active()):
+                    assert asyncio.get_event_loop().time() < deadline, \
+                        "a and b never filled the slot and the queue"
+                    await asyncio.sleep(0.002)
                 saw_429, retry_after = False, None
                 for _ in range(100):           # while a+b occupy slot+queue
                     status, headers, _ = await _post(

@@ -427,14 +427,21 @@ class TestLockstepCompletionFix:
         nothing past its EOS."""
         model, params = _base_model()
         eng = Engine(model, params, batch_slots=2, max_len=48)
-        probe = [Request(prompt=jnp.array(PROMPTS[0], jnp.int32), max_new=10)]
-        eng.generate_requests(probe)
+        # EOS must be a token whose FIRST occurrence is at index 2, so
+        # generation stops exactly there: probe prompts until one has it
+        for prompt in PROMPTS:
+            probe = [Request(prompt=jnp.array(prompt, jnp.int32), max_new=10)]
+            eng.generate_requests(probe)
+            if probe[0].out[2] not in probe[0].out[:2]:
+                break
+        else:
+            pytest.fail("no prompt's greedy stream has a fresh token at 2")
         eos = probe[0].out[2]
         calls = [0]
         real = eng._decode
         eng._decode = lambda *a, **k: (calls.__setitem__(0, calls[0] + 1)
                                        or real(*a, **k))
-        reqs = [Request(prompt=jnp.array(PROMPTS[0], jnp.int32), max_new=10)]
+        reqs = [Request(prompt=jnp.array(prompt, jnp.int32), max_new=10)]
         eng.generate_requests(reqs, eos_id=eos)
         eng._decode = real
         assert reqs[0].out == probe[0].out[:3]     # EOS token included
