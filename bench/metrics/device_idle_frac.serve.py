@@ -1,0 +1,11 @@
+"""device_idle_frac.serve: 1 - busy / traced window on the chip of a
+serving cell (busy: the union of device op intervals), in percent."""
+from __future__ import annotations
+
+
+def read(ctx):
+    tr = ctx.get("trace")
+    if ctx.get("kind") != "serve" or tr is None:
+        return None
+    idle = tr.idle_frac()
+    return None if idle is None else 100.0 * idle
