@@ -134,7 +134,7 @@ class AdapterMethod:
         if op is None:
             raise NotImplementedError(f"{self.name} has no dense ΔW form")
         tr, aux = self.split_adapter(adapter, peft)
-        dw = op.fn(tr, aux, site.d_in, site.d_out, peft)
+        dw = op(tr, aux, site.d_in, site.d_out, peft)
         return dw.astype(out_dtype) if out_dtype is not None else dw
 
     def factored_apply(self, x: jax.Array, trainable: Dict, aux: Dict,
@@ -145,7 +145,7 @@ class AdapterMethod:
         op = self._kernel("factored_apply", peft, d1, d2)
         if op is None:
             raise NotImplementedError(self.name)
-        return op.fn(x, trainable, aux, d1, d2, peft)
+        return op(x, trainable, aux, d1, d2, peft)
 
     def bank_apply(self, x: jax.Array, trainable: Dict, aux: Dict,
                    d1: int, d2: int, peft: PEFTConfig) -> jax.Array:
@@ -154,7 +154,7 @@ class AdapterMethod:
         per-row path for methods that register no bank op."""
         op = self._kernel("bank_apply", peft, d1, d2)
         if op is not None:
-            return op.fn(x, trainable, aux, d1, d2, peft)
+            return op(x, trainable, aux, d1, d2, peft)
         return jax.vmap(
             lambda xr, tr: self.factored_apply(xr, tr, aux, d1, d2, peft)
         )(x, trainable)

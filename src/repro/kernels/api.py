@@ -83,6 +83,12 @@ class KernelOp:
     kernel-capability verifier needs to RE-DERIVE `max_dim` and the VMEM
     footprint instead of trusting the declaration (DESIGN.md §Analysis).
     None means "nothing to verify" (einsum references, XLA-op backends).
+
+    Dispatch sites call the op itself, not `fn`: `__call__` runs `fn` under
+    the name scope `{op}.{method}` (e.g. `bank_apply.fourierft`,
+    `paged_attention.attention`), so every op the registry dispatches
+    carries one stable name in the compiled program's op metadata
+    (`op_name`), whichever backend resolved.
     """
     op: str
     method: str
@@ -93,6 +99,11 @@ class KernelOp:
     requires: Optional[Callable] = None
     note: str = ""
     caps: Optional[Dict] = None
+
+    def __call__(self, *args, **kwargs):
+        import jax
+        with jax.named_scope(f"{self.op}.{self.method}"):
+            return self.fn(*args, **kwargs)
 
     def supports(self, d1: int, d2: int, peft=None,
                  platform: Optional[str] = None) -> Tuple[bool, str]:

@@ -96,6 +96,7 @@ def deltaw_pallas(c: jax.Array, u: jax.Array, v: jax.Array, d1: int, d2: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
+        name="dct_deltaw_fwd",
     )(c, u, v)
 
 
@@ -133,4 +134,5 @@ def dc_pallas(g: jax.Array, u: jax.Array, v: jax.Array, d1: int, d2: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="dct_deltaw_coef_grad",
     )(g, u, v)

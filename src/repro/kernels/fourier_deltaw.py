@@ -124,6 +124,7 @@ def deltaw_pallas(c: jax.Array, u: jax.Array, v: jax.Array, d1: int, d2: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
+        name="fourier_deltaw_fwd",
     )(c, u, v)
 
 
@@ -163,4 +164,5 @@ def dc_pallas(g: jax.Array, u: jax.Array, v: jax.Array, d1: int, d2: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="fourier_deltaw_coef_grad",
     )(g, u, v)

@@ -159,5 +159,5 @@ def fourier_deltaw(c: jax.Array, entries: jax.Array, d1: int, d2: int,
     from repro.kernels import api
     peft = PEFTConfig(method="fourierft", alpha=alpha, kernel_backend=backend)
     op = api.resolve_op("deltaw", "fourierft", peft, d1, d2)
-    out = op.fn({"c": c}, {"entries": entries}, d1, d2, peft)
+    out = op({"c": c}, {"entries": entries}, d1, d2, peft)
     return out.astype(out_dtype) if out_dtype is not None else out

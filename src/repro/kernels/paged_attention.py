@@ -187,6 +187,7 @@ def paged_attention_pallas(q, k_pages, v_pages, block_table, kv_len, *,
         out_shape=jax.ShapeDtypeStruct((B, K, R, dh), v_pages.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="paged_attention",
     )(block_table, kv_len, qr, k_pages, v_pages)
     return out.reshape(B, K, W, G, dh).transpose(0, 2, 1, 3, 4).reshape(
         B, W, H, dh)

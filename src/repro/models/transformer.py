@@ -652,7 +652,7 @@ def decode_step(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
         op = kernel_api.resolve_op(
             "paged_attention", paged_mod.OWNER, peft,
             d1=cache["pk"].shape[2], d2=cfg.head_dim)
-        paged = (batch["block_table"], op.fn)
+        paged = (batch["block_table"], op)
     kk, vk = ("pk", "pv") if paged is not None else ("k", "v")
 
     # cache lives in the scan CARRY and is updated in place per layer —
@@ -779,7 +779,7 @@ def verify_step(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
                                           mode="promise_in_bounds")
             cv = cv.at[w_page, w_off].set(v.astype(cv.dtype),
                                           mode="promise_in_bounds")
-            att = op.fn(q, ck, cv, bt, kv_len)
+            att = op(q, ck, cv, bt, kv_len)
         else:
             ck = ck.at[rows, positions].set(k.astype(ck.dtype), mode="drop")
             cv = cv.at[rows, positions].set(v.astype(cv.dtype), mode="drop")

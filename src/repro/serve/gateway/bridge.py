@@ -23,6 +23,11 @@ receives ("token", id), ("done", tokens), ("cancelled", tokens) or
 ("error", message) items; a client disconnect calls `cancel(handle)`,
 which aborts the request mid-stream through the scheduler's cancel path —
 freeing its slot and pages and unpinning its tenant's bank row.
+
+Each pump round is covered by host spans on the profiler's clock:
+`gateway.commands` (commands run between ticks), the scheduler's own
+`sched.tick`, `gateway.dispatch` (events posted to the loop) and, when
+nothing is in flight, `gateway.idle` (the bounded wait for a command).
 """
 from __future__ import annotations
 
@@ -33,6 +38,7 @@ import traceback
 from typing import Callable, Dict, List, Optional
 
 from repro.serve.engine import Request
+from repro.serve.scheduler.metrics import span
 
 
 class RequestHandle:
@@ -194,11 +200,12 @@ class SchedulerBridge:
     def _pump(self) -> None:
         sched = self.sched
         while not self._stop.is_set():
-            while True:                        # drain commands between ticks
-                try:
-                    self._exec(self._cmds.get_nowait())
-                except _queue.Empty:
-                    break
+            with span("gateway.commands"):
+                while True:                    # drain commands between ticks
+                    try:
+                        self._exec(self._cmds.get_nowait())
+                    except _queue.Empty:
+                        break
             try:
                 events = sched.tick()
             except Exception as e:             # noqa: BLE001 — fail streams
@@ -215,12 +222,16 @@ class SchedulerBridge:
                         pass
                 self._handles.clear()
                 events = []
-            for ev in events:
-                self._dispatch(ev)
+            with span("gateway.dispatch"):
+                for ev in events:
+                    self._dispatch(ev)
             if not events and not sched.slots.any_active():
                 # idle: block briefly for the next command so a quiet
                 # server doesn't spin (bounded so stop() stays responsive)
-                try:
-                    self._exec(self._cmds.get(timeout=self.idle_wait_s))
-                except _queue.Empty:
-                    pass
+                with span("gateway.idle"):
+                    try:
+                        cmd = self._cmds.get(timeout=self.idle_wait_s)
+                    except _queue.Empty:
+                        continue
+                with span("gateway.commands"):
+                    self._exec(cmd)

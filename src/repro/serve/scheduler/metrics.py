@@ -1,6 +1,10 @@
 """Serving metrics for the continuous runtime (DESIGN.md §Scheduler):
 per-request TTFT, per-step batch occupancy, end-to-end tokens/s.
 
+`span` marks the host's phases (the scheduler round, the gateway pump) on
+the profiler's own clock, so a device trace shows what the host was doing
+in each gap; with no trace running a span costs about a microsecond.
+
 Step-denominated stamps (arrival/admit/first token/finish) use the
 scheduler's decode-step clock — deterministic, replay-stable, and what the
 admission policy actually trades off. Wall-clock covers the whole drain
@@ -9,10 +13,13 @@ end-to-end throughput, not a per-step extrapolation.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 import time
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, Iterator, List, Optional, Sequence
+
+import jax
 
 
 def nearest_rank(sorted_vals: Sequence[float], q: float) -> float:
@@ -25,6 +32,33 @@ def nearest_rank(sorted_vals: Sequence[float], q: float) -> float:
     i = max(0, min(len(sorted_vals) - 1,
                    math.ceil(q * len(sorted_vals)) - 1))
     return sorted_vals[i]
+
+
+class Span:
+    """One `span`: `annotate` adds stats to its trace event while the block
+    runs (values known only inside it); `seconds` holds the block's elapsed
+    host seconds once it exits."""
+
+    def __init__(self, trace):
+        self._trace = trace
+        self.seconds = 0.0
+
+    def annotate(self, **args) -> None:
+        self._trace.set_metadata(**args)
+
+
+@contextlib.contextmanager
+def span(name: str, **args) -> Iterator[Span]:
+    """Run the block under `jax.profiler.TraceAnnotation(name, **args)` (a
+    host span in the profiler's trace, `args` as its stats) and yield its
+    `Span`."""
+    with jax.profiler.TraceAnnotation(name, **args) as trace:
+        out = Span(trace)
+        t0 = time.perf_counter()
+        try:
+            yield out
+        finally:
+            out.seconds = time.perf_counter() - t0
 
 
 @dataclass

@@ -47,7 +47,6 @@ Two throughput paths sit on top of the plain per-step decode loop:
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -57,7 +56,7 @@ import numpy as np
 
 from repro.serve.engine import BankFullError, Engine, Request
 from repro.serve.paging import PagedKVCache, PrefixCache, PrimePlan
-from repro.serve.scheduler.metrics import ServingMetrics
+from repro.serve.scheduler.metrics import ServingMetrics, span
 from repro.serve.scheduler.queue import RequestQueue, ScheduledRequest
 from repro.serve.scheduler.slots import SlotManager
 from repro.serve.tiering import (
@@ -430,47 +429,53 @@ class ContinuousScheduler:
             extra["adapter_slots"] = self.bank.slot_rows(
                 [sr.request.adapter_id], 1)
             params = {**params, "bank": self.bank.params}
-        t0 = time.perf_counter()
-        if self.pager is not None:
-            plan = self._plans.pop(sr.rid)
-            if plan.cow is not None:
-                self.cache = self._copy_page(self.cache, *plan.cow)
-            if plan.fills:
-                self._promote_fills(plan, prompt)
-            _, batch = self._bucketed_prompt(jnp.asarray(plan.tail),
-                                             int(plan.tail.shape[0]))
-            batch.update(block_table=jnp.asarray(plan.block_row[None]),
-                         slot=jnp.int32(slot),
-                         scratch_page=jnp.int32(plan.scratch_page), **extra)
-            if plan.prefix_len:
-                # warm prime: the attention window gathers only the pow2
-                # bucket of the PREFIX pages (compile count stays log-
-                # bounded) — not the full pages_per_seq window, which would
-                # cost O(tail * max_len) at long max_len. Cold primes omit
-                # both keys and take the statically window-free graph.
-                ps = self.pager.page_size
-                wp = min(_bucket(-(-plan.prefix_len // ps), lo=1),
-                         self.pager.pages_per_seq)
-                batch["window_table"] = jnp.asarray(
-                    plan.block_row[None, :wp])
-                batch["prefix_len"] = jnp.int32(plan.prefix_len)
-            nt, self.cache = self._prefill_paged(params, self.cache, batch)
-        else:
-            S = int(prompt.shape[0])
-            P, batch = self._bucketed_prompt(prompt, S)
-            batch.update(extra)
-            scratch = self.model.init_cache(1, P, dtype=self._cache_dtype)
-            nt, scratch = self._prefill(params, scratch, batch)
-            self.cache = self._write(
-                self.cache, {"k": scratch["k"], "v": scratch["v"]}, slot, S)
-        tok = int(np.asarray(nt).reshape(-1)[0])
-        if self.pager is not None:
-            # publish the prompt's chunks for future sharing only past the
-            # host sync above (async dispatch errors surface there) — a
-            # failed prime must not leave prefix-cache entries pointing at
-            # never-filled pages
-            self.pager.register_prompt(plan)
-        self.metrics.on_prime(sr.rid, time.perf_counter() - t0)
+        with span("sched.prime", rid=sr.rid) as prime:
+            if self.pager is not None:
+                plan = self._plans.pop(sr.rid)
+                if plan.cow is not None:
+                    self.cache = self._copy_page(self.cache, *plan.cow)
+                if plan.fills:
+                    self._promote_fills(plan, prompt)
+                P, batch = self._bucketed_prompt(jnp.asarray(plan.tail),
+                                                 int(plan.tail.shape[0]))
+                batch.update(block_table=jnp.asarray(plan.block_row[None]),
+                             slot=jnp.int32(slot),
+                             scratch_page=jnp.int32(plan.scratch_page),
+                             **extra)
+                if plan.prefix_len:
+                    # warm prime: the attention window gathers only the
+                    # pow2 bucket of the PREFIX pages (compile count stays
+                    # log-bounded) — not the full pages_per_seq window,
+                    # which would cost O(tail * max_len) at long max_len.
+                    # Cold primes omit both keys and take the statically
+                    # window-free graph.
+                    ps = self.pager.page_size
+                    wp = min(_bucket(-(-plan.prefix_len // ps), lo=1),
+                             self.pager.pages_per_seq)
+                    batch["window_table"] = jnp.asarray(
+                        plan.block_row[None, :wp])
+                    batch["prefix_len"] = jnp.int32(plan.prefix_len)
+                nt, self.cache = self._prefill_paged(params, self.cache,
+                                                     batch)
+            else:
+                S = int(prompt.shape[0])
+                P, batch = self._bucketed_prompt(prompt, S)
+                batch.update(extra)
+                scratch = self.model.init_cache(1, P,
+                                                dtype=self._cache_dtype)
+                nt, scratch = self._prefill(params, scratch, batch)
+                self.cache = self._write(
+                    self.cache, {"k": scratch["k"], "v": scratch["v"]},
+                    slot, S)
+            prime.annotate(bucket=P)
+            tok = int(np.asarray(nt).reshape(-1)[0])
+            if self.pager is not None:
+                # publish the prompt's chunks for future sharing only past
+                # the host sync above (async dispatch errors surface
+                # there) — a failed prime must not leave prefix-cache
+                # entries pointing at never-filled pages
+                self.pager.register_prompt(plan)
+        self.metrics.on_prime(sr.rid, prime.seconds)
         return tok
 
     def _admit_ready(self) -> Iterator[Event]:
@@ -483,7 +488,8 @@ class ContinuousScheduler:
                     sr = self.queue.pop_next(self.t, self._try_admit,
                                              resident=resident)
                 if sr is not None:
-                    yield from self._admit_one(sr)
+                    with span("sched.admit", rid=sr.rid):
+                        yield from self._admit_one(sr)
                     continue
                 # blocked: no free slot, or every arrived request deferred
                 # on pages/bank. Deferral was the only option pre-tiering;
@@ -782,20 +788,21 @@ class ContinuousScheduler:
         pending, self._pending = self._pending, []
         self._flag_dev = None
         self._flag_prev = None
-        # THE drain: one transfer per buffer  # repro: allow(host-sync)
-        arr = np.asarray(jnp.stack([nt for _, nt, _ in pending]))
-        for i, (t, _, occupants) in enumerate(pending):
-            for slot, sr in occupants:
-                if self._sr[slot] is not sr:   # finished at an earlier step
-                    continue
-                tok = int(arr[i, slot])
-                self._outs[sr.rid].append(tok)
-                self._last[slot] = tok
-                self.metrics.on_token(sr.rid, t)
-                self.queue.note_usage(sr.request.adapter_id, 1)
-                yield ("token", sr.rid, tok, t)
-                if self.slots.note_token(slot, tok):
-                    yield self._finish(slot, t)
+        with span("sched.drain"):
+            # THE drain: one transfer per buffer  # repro: allow(host-sync)
+            arr = np.asarray(jnp.stack([nt for _, nt, _ in pending]))
+            for i, (t, _, occupants) in enumerate(pending):
+                for slot, sr in occupants:
+                    if self._sr[slot] is not sr:   # finished earlier
+                        continue
+                    tok = int(arr[i, slot])
+                    self._outs[sr.rid].append(tok)
+                    self._last[slot] = tok
+                    self.metrics.on_token(sr.rid, t)
+                    self.queue.note_usage(sr.request.adapter_id, 1)
+                    yield ("token", sr.rid, tok, t)
+                    if self.slots.note_token(slot, tok):
+                        yield self._finish(slot, t)
 
     # ---- speculative decode (DESIGN.md §Speculation) ----------------------
     def _spec_decode_once(self) -> Iterator[Event]:
@@ -947,25 +954,33 @@ class ContinuousScheduler:
         an un-admittable backlog: under live traffic a later round can free
         what admission waits on (a disconnect cancels a slot, a drain
         unpins a tenant), so the async gateway pumps this from its own
-        loop (serve/gateway/bridge.py) and decides idleness itself."""
-        evs: List[Event] = list(self._admit_ready())
-        if self.slots.any_active():
-            if self.drafter is not None:
-                evs.extend(self._spec_decode_once())
+        loop (serve/gateway/bridge.py) and decides idleness itself.
+
+        Host spans (`metrics.span`): the round is `sched.tick`; inside it
+        each admitted request's `sched.admit` holds its `sched.prime`, and
+        the decode step's `sched.decode` holds any `sched.drain`."""
+        with span("sched.tick"):
+            evs: List[Event] = list(self._admit_ready())
+            active = self.slots.active_slots()
+            if active:
+                with span("sched.decode", active=len(active)):
+                    if self.drafter is not None:
+                        evs.extend(self._spec_decode_once())
+                    else:
+                        evs.extend(self._decode_once())
             else:
-                evs.extend(self._decode_once())
-        else:
-            nxt = self.queue.next_arrival()
-            if nxt is not None and nxt > self.t:
-                self.t = nxt           # idle: skip to the next arrival
-        if self.host_kv is not None:
-            # materialize the round's in-flight spills now that the decode
-            # work is dispatched (the async D2H copies overlapped it);
-            # holding them longer would pin their HBM source buffers
-            self.host_kv.settle()
-        if self.host_adapters is not None:
-            self.host_adapters.settle()
-        self.metrics.queue_depth = len(self.queue)
+                nxt = self.queue.next_arrival()
+                if nxt is not None and nxt > self.t:
+                    self.t = nxt           # idle: skip to the next arrival
+            if self.host_kv is not None:
+                # materialize the round's in-flight spills now that the
+                # decode work is dispatched (the async D2H copies
+                # overlapped it); holding them longer would pin their HBM
+                # source buffers
+                self.host_kv.settle()
+            if self.host_adapters is not None:
+                self.host_adapters.settle()
+            self.metrics.queue_depth = len(self.queue)
         return evs
 
     def events(self) -> Iterator[Event]:
