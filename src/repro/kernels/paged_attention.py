@@ -19,7 +19,12 @@ backend)``:
                 ONE page picked by `block_table[b, p]` (the gather happens
                 in the index_map — no (B, max_len) window is ever
                 materialized in HBM). Online-softmax accumulation across
-                the page steps, flash-style.
+                the page steps, flash-style. The grid stops working at
+                each slot's last live page (`last_live_page`): past it the
+                index map names that page again, so the pipeline skips the
+                copy, and the body skips the scores and the accumulate —
+                work the mask made an exact no-op. Short slots cost short
+                walks; a full slot does the whole window.
     interpret — the same kernel under Pallas interpret mode (any platform;
                 the CI conformance backend).
 
@@ -44,6 +49,12 @@ fn signature (all backends):
                                 exact 0. W == 1 reduces to the single-query
                                 decode mask (positions >= kv_len masked).
     out         (B, W, H, dh)   in v_pages.dtype
+
+The operand list of the pallas_call — (block_table s32[B, PPS], kv_len
+s32[B], q, k_pages, v_pages) — is frozen: the benchmark finds this kernel
+in a device trace by exactly those operand shapes, so the last live page is
+derived from `kv_len` inside the index map and the body rather than passed
+as a third scalar-prefetch operand.
 """
 from __future__ import annotations
 
@@ -51,6 +62,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -103,8 +115,26 @@ def paged_attention_einsum(q, k_pages, v_pages, block_table, kv_len):
 # Pallas kernel: one page per grid step, block table as scalar prefetch
 # ---------------------------------------------------------------------------
 
+def last_live_page(kv_len, window: int, page_size: int, xp=jnp):
+    """Logical index of the last page any row of a W-row window reads: row
+    W-1 sees kv_len + W - 1 columns, so its last column is kv_len + W - 2.
+    `xp` is jnp inside the kernel and np on the host."""
+    return xp.maximum(kv_len + (window - 2), 0) // page_size
+
+
+def pages_visited(kv_len, window: int, page_size: int,
+                  pages_per_seq: int) -> int:
+    """Grid steps of one pallas call that fetch a page and compute, for the
+    per-slot `kv_len` (a host array): sum over slots of last page + 1, at
+    most the slot's `pages_per_seq`. The rest of the (B, pages_per_seq)
+    grid does neither."""
+    last = last_live_page(np.asarray(kv_len, np.int64), window, page_size,
+                          xp=np)
+    return int(np.sum(np.minimum(last, pages_per_seq - 1) + 1))
+
+
 def _paged_attn_kernel(bt_ref, kvlen_ref, q_ref, k_ref, v_ref, o_ref,
-                       m_ref, l_ref, acc_ref, *, page_size, group):
+                       m_ref, l_ref, acc_ref, *, page_size, group, window):
     b = pl.program_id(0)
     p = pl.program_id(1)
 
@@ -114,33 +144,37 @@ def _paged_attn_kernel(bt_ref, kvlen_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0]                                   # (K, R, dh), r = w*G + g
-    k = k_ref[0]                                   # (ps, K, dh)
-    v = v_ref[0]
-    R, dh = q.shape[1], q.shape[2]
-    qs = q.astype(jnp.float32) * (dh ** -0.5)
-    s = jnp.einsum("krd,tkd->krt", qs, k.astype(jnp.float32),
-                   precision=_F32)
-    cols = p * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, page_size), 2)
-    # query row r = w*G + g sits at window position w and sees kv_len + w
-    # columns (causal inside the window, ragged across slots; W == 1 is the
-    # plain decode mask)
-    lim = kvlen_ref[b] + jax.lax.broadcasted_iota(
-        jnp.int32, (1, R, 1), 1) // group
-    valid = cols < lim
-    s = jnp.where(valid, s, NEG_INF)
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    # explicit mask after exp: a fully-masked page must contribute 0, not
-    # exp(NEG_INF - NEG_INF) = 1, while m is still at its -inf init
-    pexp = jnp.exp(s - m_new[..., None]) * valid.astype(jnp.float32)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + pexp.sum(axis=-1)
-    acc_ref[...] = (acc_ref[...] * corr[..., None]
-                    + jnp.einsum("krt,tkd->krd", pexp,
-                                 v.astype(jnp.float32), precision=_F32))
-    m_ref[...] = m_new
+    # pages past the last live one are fully masked for every row: they
+    # would add exactly nothing (corr == 1, pexp == 0), so skip them
+    @pl.when(p <= last_live_page(kvlen_ref[b], window, page_size))
+    def _accumulate():
+        q = q_ref[0]                               # (K, R, dh), r = w*G + g
+        k = k_ref[0]                               # (ps, K, dh)
+        v = v_ref[0]
+        R, dh = q.shape[1], q.shape[2]
+        qs = q.astype(jnp.float32) * (dh ** -0.5)
+        s = jnp.einsum("krd,tkd->krt", qs, k.astype(jnp.float32),
+                       precision=_F32)
+        cols = p * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, page_size), 2)
+        # query row r = w*G + g sits at window position w and sees
+        # kv_len + w columns (causal inside the window, ragged across
+        # slots; W == 1 is the plain decode mask)
+        lim = kvlen_ref[b] + jax.lax.broadcasted_iota(
+            jnp.int32, (1, R, 1), 1) // group
+        valid = cols < lim
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1))
+        # explicit mask after exp: a fully-masked page must contribute 0,
+        # not exp(NEG_INF - NEG_INF) = 1, while m is still at its -inf init
+        pexp = jnp.exp(s - m_new[..., None]) * valid.astype(jnp.float32)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + pexp.sum(axis=-1)
+        acc_ref[...] = (acc_ref[...] * corr[..., None]
+                        + jnp.einsum("krt,tkd->krd", pexp,
+                                     v.astype(jnp.float32), precision=_F32))
+        m_ref[...] = m_new
 
     @pl.when(p == pl.num_programs(1) - 1)
     def _done():
@@ -162,17 +196,23 @@ def paged_attention_pallas(q, k_pages, v_pages, block_table, kv_len, *,
     R = W * G
     qr = q.reshape(B, W, K, G, dh).transpose(0, 2, 1, 3, 4).reshape(
         B, K, R, dh)
+
+    def page(b, p, bt, kl):
+        # the gather: each (b, p) grid step pulls the ONE physical page the
+        # block table names for slot b's logical page p. Past the slot's
+        # last live page it names that page again, and the pipeline skips
+        # a copy whose block index did not change: no page past kv_len is
+        # ever read
+        last = last_live_page(kl[b], W, ps)
+        return (bt[b, jnp.minimum(p, last)], 0, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                     # block_table, kv_len
         grid=(B, PPS),
         in_specs=[
             pl.BlockSpec((1, K, R, dh), lambda b, p, bt, kl: (b, 0, 0, 0)),
-            # the gather: each (b, p) grid step pulls the ONE physical page
-            # the block table names for slot b's logical page p
-            pl.BlockSpec((1, ps, K, dh),
-                         lambda b, p, bt, kl: (bt[b, p], 0, 0, 0)),
-            pl.BlockSpec((1, ps, K, dh),
-                         lambda b, p, bt, kl: (bt[b, p], 0, 0, 0)),
+            pl.BlockSpec((1, ps, K, dh), page),
+            pl.BlockSpec((1, ps, K, dh), page),
         ],
         out_specs=pl.BlockSpec((1, K, R, dh),
                                lambda b, p, bt, kl: (b, 0, 0, 0)),
@@ -183,7 +223,8 @@ def paged_attention_pallas(q, k_pages, v_pages, block_table, kv_len, *,
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_attn_kernel, page_size=ps, group=G),
+        functools.partial(_paged_attn_kernel, page_size=ps, group=G,
+                          window=W),
         out_shape=jax.ShapeDtypeStruct((B, K, R, dh), v_pages.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
@@ -213,7 +254,9 @@ class _PagedAttentionOwner:
                      functools.partial(paged_attention_pallas,
                                        interpret=False),
                      platforms=("tpu",),
-                     note="scalar-prefetch page gather, online softmax",
+                     note="scalar-prefetch page gather, online softmax; "
+                          "fetches and computes pages up to each slot's "
+                          "last live page only",
                      caps=CAPS),
             KernelOp("paged_attention", self.name, "interpret",
                      functools.partial(paged_attention_pallas,

@@ -202,6 +202,9 @@ def main(argv=None):
               f"ttft {s['ttft_steps_mean']:.1f} steps (p90 "
               f"{s['ttft_steps_p90']:.1f}), "
               f"{s['tokens_per_s']:.0f} tok/s")
+        if "attn_pages_live_frac" in s:
+            print(f"paged attention: {s['attn_pages_live_frac']:.1%} of the "
+                  f"kernel's page grid visited per step")
         if "spec_accept_rate" in s:
             print(f"speculative ({args.drafter}, k={args.draft_k}): "
                   f"{s['spec_tokens_per_step']:.2f} tokens/step/slot, "

@@ -462,9 +462,10 @@ def write_slot_cache(cache: Dict, slot_cache: Dict, slot, length) -> Dict:
 
 
 def reset_slots(cache: Dict, mask) -> Dict:
-    """Retire slots: masked slots' positions return to 0 (their KV rows are
-    left as-is — dead until the next write_slot_cache overwrites them, and
-    unreadable meanwhile because kv_len masking never reaches them)."""
+    """Retire slots: masked slots' positions return to 0, where decode
+    leaves them until the next prime (their KV rows are left as-is — dead
+    until the next write_slot_cache overwrites them, and unreadable
+    meanwhile because kv_len masking never reaches them)."""
     if cache["pos"].ndim != 1:
         raise ValueError("reset_slots needs a per_slot=True cache")
     return {**cache, "pos": jnp.where(mask, 0, cache["pos"])}
@@ -681,7 +682,15 @@ def decode_step(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
     else:
         logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
         next_tokens = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)  # (B,)
-    new_cache = {kk: ck, vk: cv, "pos": pos + 1}
+    if pos.ndim:
+        # a reset slot (pos 0) holds no request — every prime leaves its
+        # slot at pos >= 1 — so it stays at 0: its kv_len stays 1, and the
+        # paged kernel visits one page for it instead of a window that
+        # grows with every step it sits free
+        pos = jnp.where(pos > 0, pos + 1, 0)
+    else:
+        pos = pos + 1
+    new_cache = {kk: ck, vk: cv, "pos": pos}
     return next_tokens, new_cache
 
 

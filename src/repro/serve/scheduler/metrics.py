@@ -115,6 +115,9 @@ class ServingMetrics:
     def __init__(self, carry: Optional["ServingMetrics"] = None):
         self.requests: Dict[int, RequestMetrics] = {}
         self.occupancy: List[float] = []       # active/slots per decode step
+        # paged cache: pages the attention kernel visits over its
+        # (slots, pages_per_seq) grid, per decode step
+        self.attn_pages_live: List[float] = []
         self.steps = 0
         self.wall_s = 0.0
         self._t0: Optional[float] = None
@@ -214,6 +217,11 @@ class ServingMetrics:
         self.steps += 1
         self.occupancy.append(active / slots)
 
+    def on_attn_pages(self, visited: int, grid: int) -> None:
+        """One decode step's paged-attention work: `visited` of the
+        kernel's `grid` page steps fetch a page and compute."""
+        self.attn_pages_live.append(visited / grid)
+
     def on_spec(self, rid: int, drafted: int, accepted: int,
                 emitted: int) -> None:
         """One slot's outcome of one verify step: `drafted` tokens offered,
@@ -267,6 +275,9 @@ class ServingMetrics:
                 out[f"n_requests_{cls}"] = float(len(vals))
                 out[f"ttft_steps_p50_{cls}"] = nearest_rank(vals, 0.50)
                 out[f"ttft_steps_p90_{cls}"] = nearest_rank(vals, 0.90)
+        pages = self.attn_pages_live
+        if pages:
+            out["attn_pages_live_frac"] = sum(pages) / len(pages)
         if self.spec_slot_steps:
             drafted = sum(r.drafted for r in self.requests.values())
             accepted = sum(r.accepted for r in self.requests.values())

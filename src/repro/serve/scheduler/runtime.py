@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention import pages_visited
 from repro.serve.engine import BankFullError, Engine, Request
 from repro.serve.paging import PagedKVCache, PrefixCache, PrimePlan
 from repro.serve.scheduler.metrics import ServingMetrics, span
@@ -732,6 +733,30 @@ class ContinuousScheduler:
             params = {**params, "bank": self.bank.params}
         return params, extra
 
+    def _kv_lens(self) -> np.ndarray:
+        """Per-slot kv_len of the next decode or verify dispatch, from host
+        state alone: an active slot sits at prompt_len + taken - 1 plus one
+        per buffered step it occupied; a free slot sits at 0 (reset, and
+        decode leaves it there). kv_len is the position + 1."""
+        pos = np.zeros((self.n_slots,), np.int64)
+        for slot in self.slots.active_slots():
+            st = self.slots.state(slot)
+            pos[slot] = st.prompt_len + st.taken - 1
+        for _, _, occupants in self._pending:
+            for slot, sr in occupants:
+                if self._sr[slot] is sr:
+                    pos[slot] += 1
+        return pos + 1
+
+    def _count_live_pages(self, window: int) -> int:
+        """Pages the paged-attention kernel fetches and computes in each
+        layer of this step's dispatch (`pages_visited`); recorded as a
+        share of its (n_slots, pages_per_seq) grid."""
+        pps = self.pager.pages_per_seq
+        n = pages_visited(self._kv_lens(), window, self.pager.page_size, pps)
+        self.metrics.on_attn_pages(n, self.n_slots * pps)
+        return n
+
     def _min_budget_left(self) -> int:
         """Tokens until the EARLIEST budget completion among active slots,
         counted from the last drain — once the buffer holds that many
@@ -958,12 +983,19 @@ class ContinuousScheduler:
 
         Host spans (`metrics.span`): the round is `sched.tick`; inside it
         each admitted request's `sched.admit` holds its `sched.prime`, and
-        the decode step's `sched.decode` holds any `sched.drain`."""
+        the decode step's `sched.decode` holds any `sched.drain`. On the
+        paged cache `sched.decode` carries `live_pages`: the pages the
+        attention kernel visits per layer in that step."""
         with span("sched.tick"):
             evs: List[Event] = list(self._admit_ready())
             active = self.slots.active_slots()
             if active:
-                with span("sched.decode", active=len(active)):
+                with span("sched.decode", active=len(active)) as dec:
+                    if self.pager is not None:
+                        window = 1 if self.drafter is None \
+                            else self.drafter.k + 1
+                        dec.annotate(
+                            live_pages=self._count_live_pages(window))
                     if self.drafter is not None:
                         evs.extend(self._spec_decode_once())
                     else:

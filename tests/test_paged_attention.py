@@ -5,7 +5,9 @@ registry capability routing (interpret on any platform, pallas TPU-gated,
 auto -> einsum off-TPU), kv_len edge cases, and the MULTI-TOKEN verify
 window (DESIGN.md §Speculation): q_len in {1, 2, 5, 9} on every backend
 against a per-row single-query loop, with W == 1 bitwise-identical to the
-historical single-query semantics."""
+historical single-query semantics; and the grid's stop at each slot's last
+live page: ragged lengths around page boundaries, a NaN-poisoned pool past
+kv_len, and the host count of the pages the kernel visits."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -146,6 +148,80 @@ class TestWindowedConformance:
                                    atol=1e-5, rtol=1e-5)
 
 
+def _assert_poison_unread(q, kp, vp, bt, kv_len, W, interpret):
+    """Point every block-table entry past a slot's last live page at one
+    NaN page and check the output is finite and equal to that of a table
+    whose entries there name a finite page."""
+    B, PPS = bt.shape
+    n_pages, ps = kp.shape[:2]
+    live = np.arange(B * PPS).reshape(B, PPS)          # distinct live pages
+    last = pa.last_live_page(np.asarray(kv_len), W, ps, xp=np)
+    past = np.arange(PPS)[None, :] > last[:, None]
+    assert past.any()
+    poison = n_pages - 1
+    assert poison >= B * PPS
+    clean = jnp.asarray(np.where(past, 0, live), jnp.int32)
+    dirty = jnp.asarray(np.where(past, poison, live), jnp.int32)
+    ref = pa.paged_attention_pallas(q, kp, vp, clean, kv_len,
+                                    interpret=interpret)
+    out = pa.paged_attention_pallas(q, kp.at[poison].set(jnp.nan),
+                                    vp.at[poison].set(jnp.nan), dirty,
+                                    kv_len, interpret=interpret)
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+class TestLastLivePage:
+    """The grid fetches and computes pages only up to each slot's last live
+    page, (kv_len + W - 2) // ps: the deepest column row W-1 may read."""
+
+    PS, PPS = 16, 4
+
+    def _ragged(self, W, dtype, seed=51):
+        q, kp, vp, bt, _ = _case(seed, B=6, n_pages=26, ps=self.PS,
+                                 pps=self.PPS, W=W)
+        # 1 (a reset slot), a window ending just short of / on / past a page
+        # boundary, a mid-page slot and a full slot
+        full = self.PPS * self.PS - W + 1
+        kv_len = jnp.asarray([1, 14, 16, 17, 40, full], jnp.int32)
+        return (q.astype(dtype), kp.astype(dtype), vp.astype(dtype), bt,
+                kv_len)
+
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                           (jnp.bfloat16, 2e-2)])
+    @pytest.mark.parametrize("W", [1, 2, 4])
+    def test_ragged_kv_len_matches_einsum(self, W, dtype, tol):
+        q, kp, vp, bt, kv_len = self._ragged(W, dtype)
+        ref = pa.paged_attention_einsum(q, kp, vp, bt, kv_len)
+        out = pa.paged_attention_pallas(q, kp, vp, bt, kv_len,
+                                        interpret=True)
+        assert out.dtype == ref.dtype
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("W", [1, 2, 4])
+    def test_pages_past_kv_len_are_never_read(self, W):
+        """Every block-table entry past a slot's last live page names a
+        page no live range names, filled with NaN: a kernel that read or
+        computed any of them would return NaN (0 * NaN in the value
+        product). The output must equal that of a pool whose entries
+        there name finite pages."""
+        _assert_poison_unread(*self._ragged(W, jnp.float32), W,
+                              interpret=True)
+
+    @pytest.mark.parametrize("kv_len,W,pages", [
+        ([1], 1, 1), ([16], 1, 1), ([17], 1, 2), ([14], 4, 2),
+        ([13], 4, 1), ([64], 1, 4), ([61], 4, 4), ([500], 1, 4),
+        ([1, 16, 17, 64], 1, 1 + 1 + 2 + 4)])
+    def test_pages_visited(self, kv_len, W, pages):
+        """The host count of the grid steps that fetch and compute: one
+        page for a reset slot, a second once a window crosses a page
+        boundary, never more than the slot's pages_per_seq."""
+        assert pa.pages_visited(np.asarray(kv_len), W, self.PS,
+                                self.PPS) == pages
+
+
 class TestRegistryRouting:
     def test_backends_registered(self):
         assert set(kernel_api.backends_for("paged_attention", pa.OWNER)) \
@@ -187,6 +263,13 @@ class TestCompiledTPU:
         out = pa.paged_attention_pallas(q, kp, vp, bt, kv_len)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-2, rtol=2e-2)
+
+    @pytest.mark.parametrize("W", [1, 4])
+    def test_pallas_skips_pages_past_kv_len(self, W):
+        q, kp, vp, bt, _ = _case(2, B=4, n_pages=40, dh=128, ps=8, pps=8,
+                                 W=W)
+        kv_len = jnp.asarray([1, 9, 30, 64 - W + 1], jnp.int32)
+        _assert_poison_unread(q, kp, vp, bt, kv_len, W, interpret=False)
 
     @pytest.mark.parametrize("W", [2, 5])
     def test_pallas_windowed_matches_einsum(self, W):

@@ -16,6 +16,7 @@ from repro.checkpoint import adapters as adapter_ckpt
 from repro.configs.base import PEFTConfig
 from repro.core import adapter as adapter_api
 from repro.core import peft as peft_mod
+from repro.kernels import paged_attention as pa
 from repro.models import build
 from repro.serve import (
     AdapterBank, BankFullError, ContinuousScheduler, Engine, Request,
@@ -221,6 +222,45 @@ class TestSlotLifecycle:
         # unpinning one lets the LRU (t-a) go
         bank.load_from_checkpoint("t-c", pinned=["t-b"])
         assert set(bank.resident_ids) == {"t-b", "t-c"}
+
+
+class TestLivePages:
+    """`attn_pages_live_frac`: the host's count of the pages the paged
+    attention kernel visits equals `pages_visited` over the device's own
+    slot positions at every decode dispatch, and a retired slot stays at
+    pos 0 (one page) for as long as it sits free."""
+
+    @pytest.mark.parametrize("eos_id", [None, 7])
+    def test_count_matches_device_positions(self, eos_id):
+        model, params = _base_model()
+        eng = Engine(model, params, batch_slots=3, max_len=48)
+        sched = ContinuousScheduler(eng, eos_id=eos_id, page_size=4)
+        seen = []
+        decode = sched._decode
+
+        def probe(p, cache, batch):
+            seen.append(np.asarray(cache["pos"]))
+            return decode(p, cache, batch)
+
+        sched._decode = probe
+        # slot 1 retires after two tokens; slots 0 and 2 run on
+        sched.serve(_trace([20, 2, 14]), arrivals=[0, 0, 0])
+        pps = sched.pager.pages_per_seq
+        grid = sched.n_slots * pps
+        expected = [pa.pages_visited(pos + 1, 1, 4, pps) / grid
+                    for pos in seen]
+        assert sched.metrics.attn_pages_live == expected
+        assert sched.metrics.summary()["attn_pages_live_frac"] \
+            == pytest.approx(sum(expected) / len(expected))
+        # the retired slot decoded at pos > 0, then sat free at pos 0
+        # (kv_len 1, one page) through every later step
+        pos1 = [int(pos[1]) for pos in seen]
+        retired = pos1.index(0)
+        assert retired > 0 and max(pos1[:retired]) > 0
+        assert len(pos1) - retired >= 5
+        assert set(pos1[retired:]) == {0}
+        assert all(pa.pages_visited([p + 1], 1, 4, pps) == 1
+                   for p in pos1[retired:])
 
 
 class TestSlotManagerInvariants:
