@@ -15,7 +15,8 @@ claims, and fail when the declaration is LOOSER than the derivation:
   32500 against a derived 32768).
 
 - **VMEM footprint**: basis blocks + tile accumulator at the declared
-  block sizes (×2 for double buffering) must fit the 16 MB VMEM budget.
+  block sizes (×2 for double buffering; bases held in scratch count once,
+  with the resident dc block beside them) must fit the 16 MB VMEM budget.
 
 - **paged-attention scratch** (`paged_attention` caps): the declared
   online-softmax scratch dims must equal the canonical derivation —
@@ -69,13 +70,18 @@ def derived_phase_bound(caps: Dict) -> int:
 
 
 def derived_deltaw_vmem(caps: Dict) -> int:
-    """Per-grid-step VMEM bytes of the deltaw kernels at the declared block
-    sizes: trig basis blocks for both axes, the (bm, bn) output tile, and
-    the three (n,) entry vectors — doubled for double buffering."""
+    """VMEM bytes of the deltaw kernels at the declared block sizes: trig
+    basis blocks for both axes, the (bm, bn) output tile, and the three
+    (n,) entry vectors, all doubled for double buffering — or, where the
+    caps declare `bases: "scratch"`, the bases once as scratch carried over
+    the layer axis, the tile and entry blocks doubled, and the dc output
+    resident for the whole call, one (n,) row per layer of `l_ref`."""
     bm, bn, n = caps["bm"], caps["bn"], caps["n_ref"]
     basis = caps["trig_terms"] * (bm + bn) * n * 4
     tile = bm * bn * 4
     entries = 3 * n * 4
+    if caps.get("bases") == "scratch":
+        return basis + DOUBLE_BUFFER * (tile + entries) + caps["l_ref"] * n * 4
     return DOUBLE_BUFFER * (basis + tile + entries)
 
 

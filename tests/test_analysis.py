@@ -187,6 +187,23 @@ class TestKernelAudit:
         assert kernel_audit.derived_phase_bound(fourier_deltaw.CAPS) == 46336
         assert kernel_audit.derived_phase_bound(dct_deltaw.CAPS) == 32768
 
+    def test_deltaw_vmem_counts_scratch_bases_once(self):
+        """FourierFT holds its bases in scratch carried over the layer axis
+        (counted once, beside the resident dc block); the DCT kernels
+        still double-buffer per-step basis blocks."""
+        from repro.kernels import dct_deltaw, fourier_deltaw
+        bm = bn = 256
+        n = 1024
+        tile, entries = bm * bn * 4, 3 * n * 4
+        assert kernel_audit.derived_deltaw_vmem(fourier_deltaw.CAPS) == (
+            2 * (bm + bn) * n * 4 + 2 * (tile + entries) + 128 * n * 4)
+        assert kernel_audit.derived_deltaw_vmem(dct_deltaw.CAPS) == \
+            2 * ((bm + bn) * n * 4 + tile + entries)
+        deep = dataclasses.replace(
+            self._op("fourierft"),
+            caps={**fourier_deltaw.CAPS, "l_ref": 4096})
+        assert rules(kernel_audit.audit_op(deep)) == ["vmem-over-budget"]
+
     def test_registry_clean(self):
         assert kernel_audit.run() == []
 

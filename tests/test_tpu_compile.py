@@ -79,6 +79,28 @@ def test_stacked_deltaw_grad_compiles(one_chip, method):
     assert _has_kernel(compiled)
 
 
+@pytest.mark.parametrize("L,d1,d2", [
+    (36, 2560, 1024),          # qwen3-4b wv (wq: the harness tests above)
+    (12, 4096, 4096),          # yi-9b wq, one chip's quarter of 48 layers
+])
+def test_fourier_deltaw_stack_kernels_compile(one_chip, L, d1, d2):
+    """The FourierFT kernels at the other stacks and widths the train cells
+    run: the scratch bases carried over the layer axis and dc's resident
+    (L, 1, n) output block fit the scoped VMEM limit."""
+    from repro.kernels import fourier_deltaw as fk
+    npad = 1024
+    d1p, d2p = -(-d1 // fk.DEFAULT_BM) * fk.DEFAULT_BM, \
+        -(-d2 // fk.DEFAULT_BN) * fk.DEFAULT_BN
+    entry = _spec((1, npad), jnp.int32, one_chip)
+    fwd = jax.jit(lambda c, u, v: fk.deltaw_pallas(c, u, v, d1, d2, 300.0)
+                  ).lower(_spec((L, 1, npad), jnp.float32, one_chip),
+                          entry, entry).compile()
+    dc = jax.jit(lambda g, u, v: fk.dc_pallas(g, u, v, d1, d2, 300.0)
+                 ).lower(_spec((L, d1p, d2p), jnp.float32, one_chip),
+                         entry, entry).compile()
+    assert _has_kernel(fwd) and _has_kernel(dc)
+
+
 @pytest.mark.parametrize("W", [1, 5])
 def test_paged_attention_compiles(one_chip, W):
     B, n_pages, pps = 4, 64, 16
