@@ -36,7 +36,6 @@ MODULES = [
     "benchmarks.bench_serve_gateway",
     "benchmarks.bench_serve_tiering",
     "benchmarks.bench_analysis",
-    "benchmarks.bench_sharding_plan",
 ]
 
 SERVE_JSON = pathlib.Path(__file__).resolve().parent.parent \
@@ -100,8 +99,6 @@ def main() -> None:
         print(f"# serving rows -> {SERVE_JSON}", flush=True)
     if dump_prefix_json(ROWS, "analysis", ANALYSIS_JSON):
         print(f"# analysis rows -> {ANALYSIS_JSON}", flush=True)
-    if dump_prefix_json(ROWS, "sharding_plan", ANALYSIS_JSON):
-        print(f"# sharding-plan rows -> {ANALYSIS_JSON}", flush=True)
     if failed:
         print(f"# FAILED: {failed}")
         sys.exit(1)

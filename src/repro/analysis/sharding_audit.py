@@ -5,12 +5,9 @@ replicates (params) or rides the generic batch fall-through (caches/
 batches). That fall-through is how the mamba2/hybrid families initially
 shipped with undecided placements: the engine never errored, it just
 replicated whatever it didn't recognize. This pass makes the decision
-explicit — but since PR 10 it audits the RESOLVED PLAN, i.e. whatever
-`dist/plan.PlanSource` actually produced for the cell (the rule table by
-default, a searched or checked-in plan otherwise), via
-`PlanSource.decision(section, path, shape)`. A plan-table hit counts as a
-decision; a miss falls back to the source's fallback rules, and only a leaf
-NO layer decided is flagged.
+explicit: it asks the rule table, via `dist/plan.RulesSource.decision
+(section, path, shape)`, which named rule placed each leaf, and flags a
+leaf that no rule decided.
 
 Coverage spans every tree serving and training place:
 
@@ -62,8 +59,8 @@ def _default_source():
 
 def audit_tree(tree, label: str, section: str = "state",
                source=None) -> List[Finding]:
-    """Flag every leaf of a (shape) tree that the resolved plan source left
-    undecided — the silent fall-through instead of a named decision."""
+    """Flag every leaf of a (shape) tree that the rules left undecided —
+    the silent fall-through instead of a named decision."""
     if source is None:
         source = _default_source()
     out: List[Finding] = []
@@ -76,10 +73,8 @@ def audit_tree(tree, label: str, section: str = "state",
         out.append(Finding(
             "sharding", "uncovered", f"{label}/{name}",
             f"{section} leaf {path!r} (shape {shape}) has no placement "
-            f"decision from the resolved plan source "
-            f"({source.describe().get('source')}) — it falls through "
-            "undecided; add the name to a dist/sharding.py table or ship a "
-            "plan entry for it"))
+            "decision from the sharding rules — it falls through "
+            "undecided; add the name to a dist/sharding.py table"))
     return out
 
 
@@ -135,8 +130,7 @@ def run(methods: Tuple[str, ...] = DEFAULT_METHODS,
     """Audit every registered arch's param tree, plus the serving surfaces
     (caches/batch/bank) on the first arch. The adapter-method sweep runs on
     the first arch only — adapter leaf names don't vary per family, and
-    eval_shape per combination isn't free. `source` defaults to the rules;
-    pass a `PlanTableSource` to audit a searched/loaded plan instead."""
+    eval_shape per combination isn't free. `source` defaults to the rules."""
     from repro.models import registry
     if source is None:
         source = _default_source()

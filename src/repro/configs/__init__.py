@@ -5,7 +5,7 @@ import dataclasses
 
 from repro.configs.base import (
     ModelConfig, MoEConfig, PEFTConfig, SSMConfig, ShapeConfig, TrainConfig,
-    ZambaConfig, SHAPES, SHAPES_BY_NAME, shape_for,
+    ZambaConfig,
 )
 
 from repro.configs import (
@@ -75,6 +75,6 @@ def reduced(cfg: ModelConfig, *, layers: int = 2, width: int = 64,
 
 __all__ = [
     "ModelConfig", "MoEConfig", "PEFTConfig", "SSMConfig", "ShapeConfig",
-    "TrainConfig", "ZambaConfig", "SHAPES", "SHAPES_BY_NAME", "shape_for",
+    "TrainConfig", "ZambaConfig",
     "ARCHS", "ARCH_IDS", "PAPER_MODELS", "get", "reduced",
 ]

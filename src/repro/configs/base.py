@@ -69,8 +69,6 @@ class ModelConfig:
     # numerics
     dtype: str = "bfloat16"      # activation dtype
     param_dtype: str = "bfloat16"
-    # long-context capability flag (drives long_500k skip logic)
-    subquadratic: bool = False
 
     @property
     def attn_dim(self) -> int:
@@ -150,17 +148,6 @@ class ShapeConfig:
         return self.kind == "decode"
 
 
-# The assigned LM shape set (identical across the 10 archs).
-SHAPES: Tuple[ShapeConfig, ...] = (
-    ShapeConfig("train_4k", 4096, 256, "train"),
-    ShapeConfig("prefill_32k", 32768, 32, "prefill"),
-    ShapeConfig("decode_32k", 32768, 128, "decode"),
-    ShapeConfig("long_500k", 524288, 1, "decode"),
-)
-
-SHAPES_BY_NAME = {s.name: s for s in SHAPES}
-
-
 @dataclass(frozen=True)
 class TrainConfig:
     learning_rate: float = 3e-3
@@ -183,6 +170,3 @@ class TrainConfig:
     def replace(self, **kw) -> "TrainConfig":
         return dataclasses.replace(self, **kw)
 
-
-def shape_for(name: str) -> ShapeConfig:
-    return SHAPES_BY_NAME[name]

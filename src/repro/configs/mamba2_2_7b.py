@@ -14,5 +14,4 @@ CONFIG = ModelConfig(
     d_ff=0,
     vocab=50280,
     ssm=SSMConfig(state=128, head_dim=64, expand=2, n_groups=1),
-    subquadratic=True,
 )

@@ -1,8 +1,8 @@
 """qwen2-vl-72b [vlm] — M-RoPE, dynamic resolution (arXiv:2409.12191).
 
 80L d_model=8192 64H (GQA kv=8) d_ff=29568 vocab=152064. Backbone only: the
-vision frontend is a STUB — input_specs() provides precomputed patch/text
-embeddings (B, S, d_model) plus 3-D M-RoPE position ids (3, B, S).
+vision frontend is a STUB — batches carry precomputed patch/text embeddings
+(B, S, d_model) plus 3-D M-RoPE position ids (3, B, S).
 """
 from repro.configs.base import ModelConfig
 

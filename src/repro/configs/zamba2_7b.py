@@ -20,5 +20,4 @@ CONFIG = ModelConfig(
     vocab=32000,
     ssm=SSMConfig(state=64, head_dim=64, expand=2, n_groups=1),
     zamba=ZambaConfig(shared_every=6, shared_lora_r=0),
-    subquadratic=True,
 )

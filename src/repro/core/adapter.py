@@ -160,13 +160,10 @@ class AdapterMethod:
         )(x, trainable)
 
     def merge_site(self, eff: Dict, key: str, adapter: Dict,
-                   site: AdapterSite, peft: PEFTConfig, constrain=None,
-                   path: Optional[str] = None) -> None:
+                   site: AdapterSite, peft: PEFTConfig) -> None:
         """Fold one site into the (stacked) layer tree `eff` in place."""
-        dw = self.site_delta(adapter, site, peft, eff[key].dtype)
-        if constrain is not None:
-            dw = constrain(path or key, dw)
-        eff[key] = eff[key] + dw
+        eff[key] = eff[key] + self.site_delta(adapter, site, peft,
+                                              eff[key].dtype)
 
     # ---- accounting (paper Table 1 / §3.2) --------------------------------
     def count_trainable(self, site: AdapterSite, peft: PEFTConfig) -> int:
@@ -567,8 +564,7 @@ class BitFit(AdapterMethod):
             KernelOp("bank_apply", self.name, "einsum", _bitfit_bank_einsum),
         )
 
-    def merge_site(self, eff, key, adapter, site, peft, constrain=None,
-                   path=None):
+    def merge_site(self, eff, key, adapter, site, peft):
         bkey = key + "__b"
         db = adapter["delta_b"]
         eff[bkey] = (eff[bkey] + db) if bkey in eff else db

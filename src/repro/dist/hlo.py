@@ -12,7 +12,7 @@ call-graph multiplicity:
 - `reduce`/`sort`/collective `to_apply` reducers are NOT recursed into (they
   run per element and are charged at the call site instead).
 
-Byte accounting reports two bounds (DESIGN.md §9):
+Byte accounting reports two bounds:
 
 - `bytes` — CPU-fusion-granularity upper bound: every non-trivial
   instruction reads its operands and writes its output;
@@ -23,11 +23,13 @@ Byte accounting reports two bounds (DESIGN.md §9):
 Collective traffic is the output size of each collective × multiplicity,
 broken down by kind in `bytes_by_kind` / `count_by_kind` — all-to-all and
 collective-permute get their own buckets, never lumped into a generic
-"collective" bin (the planner's cost model prices each kind differently).
-`group_by_kind` additionally records the largest replica-group size seen per
-kind (both `{{0,1},…}` literal and `[G,S]<=[N]` iota forms; permute pairs
-count as groups of 2), which is what calibrates the cost model's
-chunk-factor n against compiled reality.
+"collective" bin. `group_by_kind` additionally records the largest
+replica-group size seen per kind (both `{{0,1},…}` literal and `[G,S]<=[N]`
+iota forms; permute pairs count as groups of 2).
+
+`analysis/hlo_lint.py` reads the parser (`parse_module`, `iter_instrs`,
+`custom_call_target`, `aliased_params`); `analyze_module`'s FLOP/byte walk
+is read only by its tests.
 """
 from __future__ import annotations
 

@@ -30,9 +30,8 @@ so per-layer loop slices keep their spec):
     pass through this rule table)
 
 FSDP (opt-in, default from `fsdp_default`): additionally shards the largest
-free matrix dim of big weights over `data`; the launch layer re-gathers
-per-layer slices inside the scan via the "fsdp_gather/<name>" constraint hook
-(see launch/dryrun_lib.make_constrain and models/transformer.make_linear).
+free matrix dim of big weights over `data`; GSPMD gathers them where the
+compute needs them.
 """
 from __future__ import annotations
 

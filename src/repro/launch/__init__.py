@@ -1,3 +1,1 @@
-# NOTE: repro.launch.dryrun sets XLA_FLAGS on import (by design, per the
-# dry-run contract); import repro.launch.dryrun_lib from library code instead.
 from repro.launch import mesh

@@ -75,10 +75,6 @@ def add_model_args(ap: argparse.ArgumentParser) -> None:
                     help="host-RAM adapter tier rows (0 disables): bank "
                          "evictions spill here; admission refills without "
                          "re-reading the checkpoint")
-    ap.add_argument("--sharding-plan", default="rules",
-                    help="rules|search|<plan.json>: where placements come "
-                         "from (dist/plan.py); search runs the planner once "
-                         "at startup")
 
 
 def _model_cfg(args):
@@ -130,21 +126,15 @@ def build_scheduler(args):
         TieringConfig,
     )
 
-    from repro.configs.base import ShapeConfig
-    from repro.dist import plan as plan_mod
     from repro.dist import sharding as shd
 
     cfg = _model_cfg(args)
     model = build(cfg, PEFTConfig(method="none"))
     mesh = make_host_mesh(model=args.model_parallel)
-    # weights are drawn under jit straight into the plan's placements
-    plan = plan_mod.resolve(
-        args.sharding_plan, model=model, mesh=mesh,
-        shape=ShapeConfig("serve", args.max_len, args.slots, "decode"),
-        workload="decode")
+    # weights are drawn under jit straight into the rules' placements
     params, _ = shd.init_placed(
         model.init, jax.random.PRNGKey(args.seed), mesh,
-        lambda t: plan.state_specs(t, mesh, cfg, False))
+        lambda t: shd.state_specs(t, mesh, cfg))
 
     bank, tenant_ids = None, []
     if args.bank_dir:
@@ -166,7 +156,7 @@ def build_scheduler(args):
                 print(f"skipping tenant {tid!r}: {e}")
 
     engine = Engine(model, params, batch_slots=args.slots,
-                    max_len=args.max_len, mesh=mesh, bank=bank, plan=plan)
+                    max_len=args.max_len, mesh=mesh, bank=bank)
     drafter = None
     if args.speculative:
         drafter = (SelfDrafter(k=args.draft_k) if args.drafter == "self"

@@ -101,10 +101,6 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--model-parallel", type=int, default=1,
                     help="TP axis size; remaining devices replicate/batch")
-    ap.add_argument("--sharding-plan", default="rules",
-                    help="rules|search|<plan.json>: where placements come "
-                         "from (dist/plan.py); search runs the planner once "
-                         "at startup")
     args = ap.parse_args(argv)
     setup_compile_cache()
 
@@ -120,7 +116,7 @@ def main(argv=None):
     model = build(cfg, peft)
     mesh = make_host_mesh(model=args.model_parallel)
     # weights are drawn under jit straight into their mesh placements (the
-    # Engine re-places the merged tree per its own plan)
+    # Engine re-places the merged tree by the same rules)
     params, _ = shd.init_placed(
         model.init, jax.random.PRNGKey(args.seed), mesh,
         lambda t: shd.state_specs(t, mesh, cfg))
@@ -161,7 +157,7 @@ def main(argv=None):
 
     slots = max(2, len(tenant_ids)) if bank else 2
     engine = Engine(model, params, batch_slots=slots, max_len=args.max_len,
-                    mesh=mesh, bank=bank, plan=args.sharding_plan)
+                    mesh=mesh, bank=bank)
     prompts = [(jnp.arange(4 + i, dtype=jnp.int32) + 3 * i) % cfg.vocab
                for i in range(slots)]
     if cfg.n_codebooks:

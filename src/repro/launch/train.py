@@ -17,10 +17,9 @@ import argparse
 import jax
 
 import repro.configs as configs
-from repro.configs.base import PEFTConfig, ShapeConfig, TrainConfig
+from repro.configs.base import PEFTConfig, TrainConfig
 from repro.core import adapter as adapter_api
 from repro.data import SyntheticLM
-from repro.dist import plan as plan_mod
 from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import build
@@ -58,10 +57,6 @@ def main(argv=None):
                          "auto per dist.sharding.fsdp_default")
     ap.add_argument("--grad-compression", default="none",
                     choices=["none", "int8_ef"])
-    ap.add_argument("--sharding-plan", default="rules",
-                    help="rules|search|<plan.json>: where placements come "
-                         "from (dist/plan.py); search runs the planner once "
-                         "at startup")
     args = ap.parse_args(argv)
     setup_compile_cache()
 
@@ -85,20 +80,14 @@ def main(argv=None):
     data = SyntheticLM(vocab=cfg.vocab, batch=args.batch, seq=args.seq,
                        seed=args.seed, task_seed=args.task_seed,
                        codebooks=cfg.n_codebooks)
-    plan_src = plan_mod.resolve(
-        args.sharding_plan, model=model, mesh=mesh,
-        shape=ShapeConfig("runtime", args.seq, args.batch, "train"),
-        workload="train")
-    if plan_src.kind != "rules":
-        print(f"sharding plan: {plan_src.describe()}")
     state, frozen = train_step.init_state(model, tcfg,
                                           jax.random.PRNGKey(args.seed),
-                                          mesh=mesh, fsdp=fsdp, plan=plan_src)
+                                          mesh=mesh, fsdp=fsdp)
     state, frozen, state_sh, frozen_sh = train_step.shard_train_state(
-        model, state, frozen, mesh, fsdp=fsdp, plan=plan_src)
+        model, state, frozen, mesh, fsdp=fsdp)
     step_fn, batch_sh = train_step.make_sharded_train_step(
         model, tcfg, mesh, state, frozen, data.batch_at(0),
-        shardings=(state_sh, frozen_sh), plan=plan_src)
+        shardings=(state_sh, frozen_sh))
     state, report = loop.run(
         step_fn, state, frozen, data, tcfg, ckpt_dir=args.ckpt_dir,
         ckpt_every=args.ckpt_every if args.ckpt_dir else 0,

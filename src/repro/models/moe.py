@@ -60,12 +60,11 @@ def assign_slots(ids: jax.Array, num_experts: int, cap: int):
 
 
 def moe_ffn(x: jax.Array, p: Dict[str, jax.Array], cfg: MoEConfig,
-            gated: bool = True, constrain=None) -> Tuple[jax.Array, jax.Array]:
+            gated: bool = True) -> Tuple[jax.Array, jax.Array]:
     """x (B, S, d); p: router (d,E), we_i/we_g (E,d,f), we_o (E,f,d).
     Returns (y (B,S,d), aux_loss)."""
     B, S, d = x.shape
     E, k = cfg.num_experts, cfg.top_k
-    cns = constrain if constrain is not None else (lambda path, t: t)
     logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
                         p["router"].astype(jnp.float32))
     gates, ids, aux = route(logits, cfg)                 # (B, S, k)
@@ -82,8 +81,7 @@ def moe_ffn(x: jax.Array, p: Dict[str, jax.Array], cfg: MoEConfig,
             cb.reshape(-1, d).astype(x.dtype), mode="drop")
 
     buf = jax.vmap(scatter_one)(ids, slots, contrib)     # (B, E, C, d)
-    buf = cns("moe/dispatch", buf)
-    # ---- expert FFN (2-D parallel: B over data, E over model) ----
+    # ---- expert FFN ----
     h = jnp.einsum("becd,edf->becf", buf, p["we_i"])
     if gated:
         g = jnp.einsum("becd,edf->becf", buf, p["we_g"])
@@ -91,7 +89,6 @@ def moe_ffn(x: jax.Array, p: Dict[str, jax.Array], cfg: MoEConfig,
     else:
         h = jax.nn.gelu(h.astype(jnp.float32)).astype(h.dtype)
     out = jnp.einsum("becf,efd->becd", h, p["we_o"])
-    out = cns("moe/dispatch", out)
     # ---- combine: batched gather + weighted reduce over k (no scatter) ----
     def gather_one(ob, eb, sb):
         return ob[eb.reshape(-1), sb.reshape(-1)].reshape(S, k, d)
@@ -99,5 +96,4 @@ def moe_ffn(x: jax.Array, p: Dict[str, jax.Array], cfg: MoEConfig,
     gathered = jax.vmap(gather_one)(out, ids, slots)     # (B, S, k, d)
     w = (gates * keep).astype(jnp.float32)               # (B, S, k)
     y = jnp.einsum("bskd,bsk->bsd", gathered.astype(jnp.float32), w)
-    y = cns("moe/tokens", y)
     return y.astype(x.dtype), aux

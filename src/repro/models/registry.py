@@ -6,19 +6,17 @@ interface across families (dense/moe/audio/vlm transformer, pure-SSM, hybrid).
     model.forward(params, batch)         -> (logits, aux)
     model.decode_step(params, cache, b)  -> (next_tokens, cache)
     model.init_cache(batch, max_len)     -> cache tree
-    model.input_specs(shape)             -> (batch specs, cache specs | None)
     model.sites                          -> adapter sites (PEFT targets)
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass, field
-from typing import Callable, Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig, PEFTConfig, ShapeConfig
+from repro.configs.base import ModelConfig, PEFTConfig
 from repro.core import adapter as adapter_api
 from repro.core import peft as peft_mod
 from repro.core.peft import AdapterSite
@@ -84,9 +82,6 @@ class Model:
     cfg: ModelConfig
     peft: PEFTConfig
     remat: str = "none"
-    # optional sharding-constraint hook `f(param_path, x) -> x`, installed by
-    # the launch layer (anchors merged W+ΔW stacks to the weight's spec)
-    constrain: Optional[Callable] = None
     # serving adapter bank: {method name: PEFTConfig profile} — static config
     # closed over by the jitted graphs; the resident rows themselves travel
     # as params["bank"] arrays (see serve/engine.py AdapterBank)
@@ -129,13 +124,13 @@ class Model:
     def forward(self, params: Dict, batch: Dict):
         return self._mod.forward(params["base"], params["peft"], batch,
                                  self.cfg, self.peft, self.sites,
-                                 remat=self.remat, constrain=self.constrain,
+                                 remat=self.remat,
                                  **self._bank_kwargs(params))
 
     def loss(self, params: Dict, batch: Dict) -> jax.Array:
         return self._mod.loss_fn(params["base"], params["peft"], batch,
                                  self.cfg, self.peft, self.sites,
-                                 remat=self.remat, constrain=self.constrain)
+                                 remat=self.remat)
 
     # split-tree loss used by the train step (grads w.r.t. trainable only)
     def loss_from_parts(self, trainable: Dict, frozen_base: Dict,
@@ -146,8 +141,7 @@ class Model:
             base = dict(base)
             base["lm_head"] = trainable["head"]
         return self._mod.loss_fn(base, adapters, batch, self.cfg, self.peft,
-                                 self.sites, remat=self.remat,
-                                 constrain=self.constrain)
+                                 self.sites, remat=self.remat)
 
     # ---- decode -----------------------------------------------------------
     def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16,
@@ -212,13 +206,12 @@ class Model:
         into the slot's pages. Returns (next_tokens, cache)."""
         fn = self._slot_mod().prefill_paged
         return fn(params["base"], params["peft"], cache, batch, self.cfg,
-                  self.peft, self.sites, constrain=self.constrain,
+                  self.peft, self.sites,
                   **self._bank_kwargs(params))
 
     def decode_step(self, params: Dict, cache: Dict, batch: Dict):
         return self._mod.decode_step(params["base"], params["peft"], cache,
                                      batch, self.cfg, self.peft, self.sites,
-                                     constrain=self.constrain,
                                      **self._bank_kwargs(params))
 
     def verify_step(self, params: Dict, cache: Dict, batch: Dict):
@@ -229,7 +222,7 @@ class Model:
         scheduler commits accepted counts via `advance_pos`."""
         fn = self._slot_mod().verify_step
         return fn(params["base"], params["peft"], cache, batch, self.cfg,
-                  self.peft, self.sites, constrain=self.constrain,
+                  self.peft, self.sites,
                   **self._bank_kwargs(params))
 
     def advance_pos(self, cache: Dict, delta):
@@ -245,7 +238,7 @@ class Model:
         fn = getattr(self._mod, "prefill", None)
         if fn is not None:
             return fn(params["base"], params["peft"], cache, batch, self.cfg,
-                      self.peft, self.sites, constrain=self.constrain,
+                      self.peft, self.sites,
                       **self._bank_kwargs(params))
         tokens = batch["tokens"]
         extra = {k: batch[k] for k in ("adapter_slots",) if k in batch}
@@ -257,42 +250,6 @@ class Model:
 
         cache, nts = jax.lax.scan(body, cache, jnp.moveaxis(tokens, 1, 0))
         return jax.tree.map(lambda a: a[-1], nts), cache
-
-    # ---- abstract input specs (dry-run) -------------------------------------
-    def input_specs(self, shape: ShapeConfig) -> Dict:
-        cfg = self.cfg
-        B, S = shape.global_batch, shape.seq_len
-        i32 = jnp.int32
-        if shape.kind in ("train", "prefill"):
-            if cfg.family == "vlm":
-                batch = {
-                    "embeds": jax.ShapeDtypeStruct((B, S, cfg.d_model), jnp.bfloat16),
-                    "positions": jax.ShapeDtypeStruct((3, B, S), i32),
-                }
-            elif cfg.n_codebooks:
-                batch = {"tokens": jax.ShapeDtypeStruct((B, S, cfg.n_codebooks), i32)}
-            else:
-                batch = {"tokens": jax.ShapeDtypeStruct((B, S), i32)}
-            if shape.kind == "train":
-                lbl = ((B, S, cfg.n_codebooks) if cfg.n_codebooks else (B, S))
-                batch["labels"] = jax.ShapeDtypeStruct(lbl, i32)
-            return batch
-        # decode: one new token against a seq_len cache
-        if cfg.family == "vlm":
-            batch = {
-                "embeds": jax.ShapeDtypeStruct((B, 1, cfg.d_model), jnp.bfloat16),
-                "positions": jax.ShapeDtypeStruct((3, B, 1), i32),
-            }
-        elif cfg.n_codebooks:
-            batch = {"tokens": jax.ShapeDtypeStruct((B, 1, cfg.n_codebooks), i32)}
-        else:
-            batch = {"tokens": jax.ShapeDtypeStruct((B, 1), i32)}
-        return batch
-
-    def cache_specs(self, shape: ShapeConfig) -> Dict:
-        return jax.eval_shape(
-            functools.partial(self.init_cache, shape.global_batch,
-                              shape.seq_len))
 
     # ---- kernels ------------------------------------------------------------
     def explain_kernels(self) -> str:

@@ -31,19 +31,17 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Dict:
 
 
 def forward(params: Dict, adapters: Dict, batch: Dict, cfg: ModelConfig,
-            peft: PEFTConfig, sites, *, remat: str = "none", constrain=None,
+            peft: PEFTConfig, sites, *, remat: str = "none",
             bank=None, bank_profiles=None):
     x = jnp.take(params["embed"], batch["tokens"], axis=0)
     eff_layers, apps = apply_peft_to_layers(
-        params["layers"], adapters, sites, peft, constrain=constrain,
+        params["layers"], adapters, sites, peft,
         bank=bank, bank_profiles=bank_profiles,
         bank_slots=batch.get("adapter_slots"))
-    linear = make_linear(apps, constrain)
-    act = (lambda t: constrain("act/hidden", t)) if constrain else (lambda t: t)
-    x = act(x)
+    linear = make_linear(apps)
 
     def body(x, lp):
-        return act(mamba2.mamba_block(lp, act(x), cfg, linear_fn=linear)), None
+        return mamba2.mamba_block(lp, x, cfg, linear_fn=linear), None
 
     x, _ = jax.lax.scan(_remat(body, remat), x, eff_layers)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -51,10 +49,9 @@ def forward(params: Dict, adapters: Dict, batch: Dict, cfg: ModelConfig,
     return logits, jnp.float32(0.0)
 
 
-def loss_fn(params, adapters, batch, cfg, peft, sites, *, remat="none",
-            constrain=None):
+def loss_fn(params, adapters, batch, cfg, peft, sites, *, remat="none"):
     logits, _ = forward(params, adapters, batch, cfg, peft, sites,
-                        remat=remat, constrain=constrain)
+                        remat=remat)
     return cross_entropy(logits, batch["labels"])
 
 
@@ -66,14 +63,14 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 
 
 def decode_step(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
-                cfg: ModelConfig, peft: PEFTConfig, sites, constrain=None,
+                cfg: ModelConfig, peft: PEFTConfig, sites,
                 bank=None, bank_profiles=None):
     x = jnp.take(params["embed"], batch["tokens"], axis=0)    # (B, 1, d)
     eff_layers, apps = apply_peft_to_layers(
-        params["layers"], adapters, sites, peft, constrain=constrain,
+        params["layers"], adapters, sites, peft,
         bank=bank, bank_profiles=bank_profiles,
         bank_slots=batch.get("adapter_slots"))
-    linear = make_linear(apps, constrain)
+    linear = make_linear(apps)
 
     # caches in the scan carry (in-place per-layer update; see transformer.py)
     def body(carry, lp_i):

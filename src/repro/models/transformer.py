@@ -54,21 +54,16 @@ class SiteApp:
     banked: bool = False
 
 
-def make_linear(apps: Dict[str, List[SiteApp]], constrain=None):
+def make_linear(apps: Dict[str, List[SiteApp]]):
     """Returns linear(lp, name, x): y = x @ lp[name] + bias + adapter apps.
 
     Each `SiteApp` at `name` reads its trainable per-layer slices out of `lp`
     (the scanned layer tree) and adds `method.factored_apply` — or
     `method.bank_apply` for per-request resident adapters — to y, under the
-    app's own PEFTConfig (the global config has no say here).
-    `constrain` (launch-layer hook) implements FSDP: weight slices stored
-    `data`-sharded are all-gathered here, inside the layer loop, where the
-    gather is loop-variant and cannot be hoisted into a full-stack gather."""
+    app's own PEFTConfig (the global config has no say here)."""
 
     def linear(lp: Dict, name: str, x: jax.Array) -> jax.Array:
         w = lp[name]
-        if constrain is not None and w.ndim >= 2:
-            w = constrain("fsdp_gather/" + name, w)
         y = jnp.einsum("...d,df->...f", x, w)
         if name + "__b" in lp:
             y = y + lp[name + "__b"].astype(y.dtype)
@@ -89,7 +84,7 @@ def _app_tag(kind: str, method_name: str) -> str:
 
 
 def apply_peft_to_layers(layers: Dict, adapters: Dict, sites, peft: PEFTConfig,
-                         prefix: str = "layers/", constrain=None,
+                         prefix: str = "layers/",
                          bank: Optional[Dict] = None,
                          bank_profiles: Optional[Dict[str, PEFTConfig]] = None,
                          bank_slots: Optional[Dict] = None):
@@ -103,13 +98,7 @@ def apply_peft_to_layers(layers: Dict, adapters: Dict, sites, peft: PEFTConfig,
     leaves with `bank_slots[method]` (B,) ONCE here, outside the scan, and
     enter the scanned tree as (L, B, …) leaves; row K is the reserved zero
     row, so requests not using a method contribute exactly zero (methods are
-    linear in their trainables — see core/adapter.py).
-
-    `constrain(path, x)`: optional sharding-constraint hook (set by the launch
-    layer) pinning merged W+ΔW stacks to the weight's partition spec — without
-    it GSPMD has no sharding anchor for the materialization einsum and falls
-    back to involuntary full rematerialization (measured: +15GB temps on
-    yi-6b train_4k)."""
+    linear in their trainables — see core/adapter.py)."""
     eff = dict(layers)
     apps: Dict[str, List[SiteApp]] = {}
     method = adapter_api.resolve(peft.method)
@@ -120,8 +109,7 @@ def apply_peft_to_layers(layers: Dict, adapters: Dict, sites, peft: PEFTConfig,
         key = full_name[len(prefix):]
         site = site_by_name[full_name]
         if peft.strategy == "merged" and method.mergeable:
-            method.merge_site(eff, key, ad, site, peft, constrain=constrain,
-                              path=full_name)
+            method.merge_site(eff, key, ad, site, peft)
             continue
         tag = _app_tag("ad", method.name)
         trainable = set(method.trainable_leaves(peft))
@@ -292,12 +280,10 @@ def _attn_block(lp: Dict, x: jax.Array, cfg: ModelConfig, linear,
     return x + out, new_kv
 
 
-def _mlp_block(lp: Dict, x: jax.Array, cfg: ModelConfig, linear,
-               constrain=None):
+def _mlp_block(lp: Dict, x: jax.Array, cfg: ModelConfig, linear):
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     if cfg.moe is not None:
-        y, aux = moe_mod.moe_ffn(h, lp, cfg.moe, gated=cfg.gated_mlp,
-                                 constrain=constrain)
+        y, aux = moe_mod.moe_ffn(h, lp, cfg.moe, gated=cfg.gated_mlp)
         return x + y, aux
     hi = linear(lp, "wi", h)
     if cfg.gated_mlp:
@@ -319,7 +305,7 @@ def _remat(fn, mode: str):
 
 def forward(params: Dict, adapters: Dict, batch: Dict, cfg: ModelConfig,
             peft: PEFTConfig, sites, *, remat: str = "none",
-            constrain=None, bank=None,
+            bank=None,
             bank_profiles=None) -> Tuple[jax.Array, jax.Array]:
     """Train/prefill forward. Returns (logits, moe_aux_loss)."""
     x = _embed(params, cfg, batch)
@@ -328,19 +314,16 @@ def forward(params: Dict, adapters: Dict, batch: Dict, cfg: ModelConfig,
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     eff_layers, apps = apply_peft_to_layers(
-        params["layers"], adapters, sites, peft, constrain=constrain,
+        params["layers"], adapters, sites, peft,
         bank=bank, bank_profiles=bank_profiles,
         bank_slots=batch.get("adapter_slots"))
-    linear = make_linear(apps, constrain)
-    act = (lambda t: constrain("act/hidden", t)) if constrain else (lambda t: t)
-    x = act(x)
+    linear = make_linear(apps)
 
     def body(carry, lp):
         x, aux = carry
-        x = act(x)
         x, _ = _attn_block(lp, x, cfg, linear, positions)
-        x, aux_l = _mlp_block(lp, x, cfg, linear, constrain)
-        return (act(x), aux + aux_l), None
+        x, aux_l = _mlp_block(lp, x, cfg, linear)
+        return (x, aux + aux_l), None
 
     (x, moe_aux), _ = jax.lax.scan(_remat(body, remat), (x, jnp.float32(0.0)),
                                    eff_layers)
@@ -353,10 +336,9 @@ def forward(params: Dict, adapters: Dict, batch: Dict, cfg: ModelConfig,
 
 
 def loss_fn(params: Dict, adapters: Dict, batch: Dict, cfg: ModelConfig,
-            peft: PEFTConfig, sites, *, remat: str = "none",
-            constrain=None) -> jax.Array:
+            peft: PEFTConfig, sites, *, remat: str = "none") -> jax.Array:
     logits, moe_aux = forward(params, adapters, batch, cfg, peft, sites,
-                              remat=remat, constrain=constrain)
+                              remat=remat)
     ce = cross_entropy(logits, batch["labels"])
     if cfg.moe is not None:
         ce = ce + cfg.moe.aux_loss_weight * moe_aux
@@ -372,7 +354,7 @@ def loss_fn(params: Dict, adapters: Dict, batch: Dict, cfg: ModelConfig,
 
 def prefill(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
             cfg: ModelConfig, peft: PEFTConfig, sites,
-            constrain=None, bank=None,
+            bank=None,
             bank_profiles=None) -> Tuple[jax.Array, Dict]:
     """Process a (B, S) prompt against a fresh cache (pos must be 0).
     Returns (next_tokens after the last prompt token, cache at pos=S).
@@ -389,10 +371,10 @@ def prefill(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     eff_layers, apps = apply_peft_to_layers(
-        params["layers"], adapters, sites, peft, constrain=constrain,
+        params["layers"], adapters, sites, peft,
         bank=bank, bank_profiles=bank_profiles,
         bank_slots=batch.get("adapter_slots"))
-    linear = make_linear(apps, constrain)
+    linear = make_linear(apps)
 
     # cache lives in the scan carry and is written in place per layer —
     # threading K/V through scan ys would materialize a second (L,B,S,K,hd)
@@ -405,7 +387,7 @@ def prefill(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
             ck_all, k.astype(ck_all.dtype)[None], (li, 0, 0, 0, 0))
         cv_all = jax.lax.dynamic_update_slice(
             cv_all, v.astype(cv_all.dtype)[None], (li, 0, 0, 0, 0))
-        x, _ = _mlp_block(lp, x, cfg, linear, constrain)
+        x, _ = _mlp_block(lp, x, cfg, linear)
         return (x, ck_all, cv_all), None
 
     (x, ck, cv), _ = jax.lax.scan(
@@ -511,7 +493,7 @@ def copy_page(cache: Dict, src, dst) -> Dict:
 
 def prefill_paged(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
                   cfg: ModelConfig, peft: PEFTConfig, sites,
-                  constrain=None, bank=None,
+                  bank=None,
                   bank_profiles=None) -> Tuple[jax.Array, Dict]:
     """Shared-prefix tail prefill into the page pool: run ONLY the unshared
     tail of a prompt whose first `prefix_len` tokens are already resident
@@ -549,10 +531,10 @@ def prefill_paged(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
     positions = prefix_len + jnp.broadcast_to(
         jnp.arange(T, dtype=jnp.int32), (B, T))
     eff_layers, apps = apply_peft_to_layers(
-        params["layers"], adapters, sites, peft, constrain=constrain,
+        params["layers"], adapters, sites, peft,
         bank=bank, bank_profiles=bank_profiles,
         bank_slots=batch.get("adapter_slots"))
-    linear = make_linear(apps, constrain)
+    linear = make_linear(apps)
     bt = batch["block_table"]                        # (1, PPS)
     ps = cache["pk"].shape[2]
     cap = bt.shape[1] * ps
@@ -607,7 +589,7 @@ def prefill_paged(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
                                       mode="promise_in_bounds")
         pk_all = jax.lax.dynamic_update_index_in_dim(pk_all, pk, li, 0)
         pv_all = jax.lax.dynamic_update_index_in_dim(pv_all, pv, li, 0)
-        x, _ = _mlp_block(lp, x, cfg, linear, constrain)
+        x, _ = _mlp_block(lp, x, cfg, linear)
         return (x, pk_all, pv_all), None
 
     (x, pk, pv), _ = jax.lax.scan(
@@ -624,7 +606,7 @@ def prefill_paged(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
 
 def decode_step(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
                 cfg: ModelConfig, peft: PEFTConfig, sites,
-                constrain=None, bank=None,
+                bank=None,
                 bank_profiles=None) -> Tuple[jax.Array, Dict]:
     """One token for every sequence in the batch. batch: tokens (B, 1) (or
     embeds (B,1,d), positions (3,B,1) for vlm). Returns (next_tokens, cache).
@@ -643,10 +625,10 @@ def decode_step(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
         else:                       # per-slot cache: row i sits at pos[i]
             positions = pos.astype(jnp.int32)[:, None]
     eff_layers, apps = apply_peft_to_layers(
-        params["layers"], adapters, sites, peft, constrain=constrain,
+        params["layers"], adapters, sites, peft,
         bank=bank, bank_profiles=bank_profiles,
         bank_slots=batch.get("adapter_slots"))
-    linear = make_linear(apps, constrain)
+    linear = make_linear(apps)
     paged = None
     if "pk" in cache:
         from repro.kernels import paged_attention as paged_mod
@@ -669,7 +651,7 @@ def decode_step(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
                                   paged=paged)
         ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, li, 0)
         cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, li, 0)
-        x, _ = _mlp_block(lp, x, cfg, linear, constrain)
+        x, _ = _mlp_block(lp, x, cfg, linear)
         return (x, ck_all, cv_all), None
 
     (x, ck, cv), _ = jax.lax.scan(
@@ -707,7 +689,7 @@ def advance_pos(cache: Dict, delta) -> Dict:
 
 def verify_step(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
                 cfg: ModelConfig, peft: PEFTConfig, sites,
-                constrain=None, bank=None,
+                bank=None,
                 bank_profiles=None) -> Tuple[jax.Array, Dict]:
     """Draft verification: one batched forward over a short window of W
     consecutive tokens per slot (DESIGN.md §Speculation). batch["tokens"]
@@ -736,10 +718,10 @@ def verify_step(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
     positions = (pos.astype(jnp.int32)[:, None]
                  + jnp.arange(W, dtype=jnp.int32)[None, :])   # (B, W)
     eff_layers, apps = apply_peft_to_layers(
-        params["layers"], adapters, sites, peft, constrain=constrain,
+        params["layers"], adapters, sites, peft,
         bank=bank, bank_profiles=bank_profiles,
         bank_slots=batch.get("adapter_slots"))
-    linear = make_linear(apps, constrain)
+    linear = make_linear(apps)
     paged = "pk" in cache
     kv_len = pos + 1                                # row-0 validity
     if paged:
@@ -796,7 +778,7 @@ def verify_step(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
         x = x + linear(lp, "wo", att.reshape(B, W, cfg.attn_dim))
         ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, li, 0)
         cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, li, 0)
-        x, _ = _mlp_block(lp, x, cfg, linear, constrain)
+        x, _ = _mlp_block(lp, x, cfg, linear)
         return (x, ck_all, cv_all), None
 
     (x, ck, cv), _ = jax.lax.scan(

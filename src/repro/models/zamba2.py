@@ -142,7 +142,7 @@ def _row_views(cfg: ModelConfig, rows: Dict):
 
 
 def forward(params: Dict, adapters: Dict, batch: Dict, cfg: ModelConfig,
-            peft: PEFTConfig, sites, *, remat: str = "none", constrain=None,
+            peft: PEFTConfig, sites, *, remat: str = "none",
             bank=None, bank_profiles=None):
     x = jnp.take(params["embed"], batch["tokens"], axis=0)
     B, S = x.shape[0], x.shape[1]
@@ -150,24 +150,23 @@ def forward(params: Dict, adapters: Dict, batch: Dict, cfg: ModelConfig,
     mamba_adapters = {k: v for k, v in adapters.items()
                       if k.startswith("layers/")}
     eff_layers, apps = apply_peft_to_layers(
-        params["layers"], mamba_adapters, sites, peft, constrain=constrain,
+        params["layers"], mamba_adapters, sites, peft,
         bank=bank, bank_profiles=bank_profiles,
         bank_slots=batch.get("adapter_slots"))
-    linear = make_linear(apps, constrain)
-    act = (lambda t: constrain("act/hidden", t)) if constrain else (lambda t: t)
+    linear = make_linear(apps)
     rows, shared_apps = _shared_adapter_rows(adapters, peft)
     main_layers, tail_layers = _group_views(cfg, eff_layers)
     main_rows, tail_rows = _row_views(cfg, rows)
 
     def mamba_body(x, lp):
-        return act(mamba2.mamba_block(lp, act(x), cfg, linear_fn=linear)), None
+        return mamba2.mamba_block(lp, x, cfg, linear_fn=linear), None
 
     def group_body(x, xs):
         gl, ad_row = xs
-        x, _ = _shared_block(act(x), params["shared"], ad_row, shared_apps, cfg,
+        x, _ = _shared_block(x, params["shared"], ad_row, shared_apps, cfg,
                              peft, positions)
         x, _ = jax.lax.scan(mamba_body, x, gl)
-        return act(x), None
+        return x, None
 
     x, _ = jax.lax.scan(_remat(group_body, remat), x, (main_layers, main_rows))
     if tail_layers is not None:
@@ -179,10 +178,9 @@ def forward(params: Dict, adapters: Dict, batch: Dict, cfg: ModelConfig,
     return logits, jnp.float32(0.0)
 
 
-def loss_fn(params, adapters, batch, cfg, peft, sites, *, remat="none",
-            constrain=None):
+def loss_fn(params, adapters, batch, cfg, peft, sites, *, remat="none"):
     logits, _ = forward(params, adapters, batch, cfg, peft, sites,
-                        remat=remat, constrain=constrain)
+                        remat=remat)
     return cross_entropy(logits, batch["labels"])
 
 
@@ -197,7 +195,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 
 
 def decode_step(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
-                cfg: ModelConfig, peft: PEFTConfig, sites, constrain=None,
+                cfg: ModelConfig, peft: PEFTConfig, sites,
                 bank=None, bank_profiles=None):
     x = jnp.take(params["embed"], batch["tokens"], axis=0)    # (B, 1, d)
     B = x.shape[0]
@@ -206,10 +204,10 @@ def decode_step(params: Dict, adapters: Dict, cache: Dict, batch: Dict,
     mamba_adapters = {k: v for k, v in adapters.items()
                       if k.startswith("layers/")}
     eff_layers, apps = apply_peft_to_layers(
-        params["layers"], mamba_adapters, sites, peft, constrain=constrain,
+        params["layers"], mamba_adapters, sites, peft,
         bank=bank, bank_profiles=bank_profiles,
         bank_slots=batch.get("adapter_slots"))
-    linear = make_linear(apps, constrain)
+    linear = make_linear(apps)
     rows, shared_apps = _shared_adapter_rows(adapters, peft)
     n_full, tail_len = _split(cfg)
 

@@ -374,16 +374,9 @@ class Engine:
             model = dataclasses.replace(model,
                                         bank_profiles=dict(bank.profiles))
         self.mesh = mesh
-        # plan: a dist.plan.PlanSource (or a --sharding-plan string); the
-        # rules source reproduces the pre-PR-10 placements byte-identically
+        # plan: a dist.plan.RulesSource; None places by the rules
         from repro.dist import plan as plan_mod
-        if plan is None or isinstance(plan, str):
-            shape = ShapeConfig("serve", max_len, batch_slots, "decode")
-            self.plan_source = plan_mod.resolve(plan, model=model, mesh=mesh,
-                                                shape=shape,
-                                                workload="decode")
-        else:
-            self.plan_source = plan
+        self.plan_source = plan if plan is not None else plan_mod.RulesSource()
         if mesh is not None:
             from repro.dist import sharding as shd
             specs = self.plan_source.state_specs(params, mesh, model.cfg,

@@ -4,8 +4,8 @@ anomaly-guarded updates.
 Parameters are split into (trainable, frozen): gradients are taken w.r.t. the
 trainable subtree only, so XLA dead-code-eliminates every frozen-weight
 gradient GEMM — the structural memory/compute win of PEFT. The frozen subtree
-is passed as a separate argument (not captured) so the dry-run can shard and
-donate it explicitly.
+is passed as a separate argument (not captured) so the sharded step can place
+and donate it explicitly.
 
 Anomaly guard (fault tolerance): non-finite or exploding loss/grad-norm skips
 the update (params/opt unchanged) and increments `anomalies` in the state —
@@ -63,12 +63,12 @@ def join_params(model: Model, trainable: Dict, frozen: Dict) -> Dict:
 
 
 def init_state(model: Model, tcfg: TrainConfig, rng: jax.Array, mesh=None,
-               fsdp: bool = None, plan=None) -> Tuple[Dict, Dict]:
+               fsdp: bool = None) -> Tuple[Dict, Dict]:
     """-> (state, frozen). state = {step, trainable, opt, loss_ema, anomalies}
     (+ ef_residual when int8 error-feedback grad compression is on).
 
     With `mesh`, the pair is built under jit straight into the placements
-    `shard_train_state` gives it (same `fsdp`/`plan`), so no device ever
+    `shard_train_state` gives it (same `fsdp`), so no device ever
     holds the whole tree on the way there."""
     def build(rng):
         params = model.init(rng)
@@ -88,10 +88,9 @@ def init_state(model: Model, tcfg: TrainConfig, rng: jax.Array, mesh=None,
     if mesh is None:
         return build(rng)
     from repro.dist import sharding as shd
-    src = _plan_source(plan)
     if fsdp is None:
         fsdp = shd.fsdp_default(model.cfg, mesh)
-    specs = lambda t: src.state_specs(t, mesh, model.cfg, fsdp)
+    specs = lambda t: shd.state_specs(t, mesh, model.cfg, fsdp)
     pair, _ = shd.init_placed(build, rng, mesh,
                               lambda t: (specs(t[0]), specs(t[1])))
     return pair
@@ -181,44 +180,32 @@ def make_train_step(model: Model, tcfg: TrainConfig) -> Callable:
 
 
 # ---------------------------------------------------------------------------
-# Mesh placement (dist/plan.py sources; rules remain the default)
+# Mesh placement (the dist/sharding.py rules)
 # ---------------------------------------------------------------------------
 
-def _plan_source(plan):
-    from repro.dist import plan as plan_mod
-    if plan is None or isinstance(plan, plan_mod.PlanSource):
-        return plan or plan_mod.RulesSource()
-    # a ShardingPlan object or a plan-file path
-    if isinstance(plan, str):
-        return plan_mod.PlanTableSource(plan_mod.ShardingPlan.load(plan))
-    return plan_mod.PlanTableSource(plan)
-
-
 def shard_train_state(model: Model, state: Dict, frozen: Dict, mesh,
-                      fsdp: bool = None, plan=None):
-    """Place (state, frozen) on `mesh` per the resolved plan source
-    (`plan`: None/rules | PlanSource | ShardingPlan | plan-file path).
+                      fsdp: bool = None):
+    """Place (state, frozen) on `mesh` per the sharding rules.
     Returns (state, frozen, state_sharding, frozen_sharding)."""
     from repro.dist import sharding as shd
-    src = _plan_source(plan)
     if fsdp is None:
         fsdp = shd.fsdp_default(model.cfg, mesh)
     st_sh = shd.named(state,
-                      src.state_specs(state, mesh, model.cfg, fsdp), mesh)
+                      shd.state_specs(state, mesh, model.cfg, fsdp), mesh)
     fr_sh = shd.named(frozen,
-                      src.state_specs(frozen, mesh, model.cfg, fsdp), mesh)
+                      shd.state_specs(frozen, mesh, model.cfg, fsdp), mesh)
     return (jax.device_put(state, st_sh), jax.device_put(frozen, fr_sh),
             st_sh, fr_sh)
 
 
 def make_sharded_train_step(model: Model, tcfg: TrainConfig, mesh,
                             state: Dict, frozen: Dict, batch_example: Dict,
-                            fsdp: bool = None, shardings=None, plan=None):
+                            fsdp: bool = None, shardings=None):
     """jit the train step with explicit mesh shardings and donated state.
     `batch_example` may be real arrays or ShapeDtypeStructs; its leading dim
     is the global batch. `shardings`: the (state_sharding, frozen_sharding)
     pair from shard_train_state — pass it so placement and jit in_shardings
-    share one source of truth (recomputed from `fsdp`/`plan` only when
+    share one source of truth (recomputed from `fsdp` only when
     absent). Returns (jitted_step, batch_sharding) — feed batches through
     `jax.device_put(batch, batch_sharding)` (train/loop.py does this when
     given `batch_sharding`)."""
@@ -226,23 +213,22 @@ def make_sharded_train_step(model: Model, tcfg: TrainConfig, mesh,
 
     from repro.configs.base import ShapeConfig
     from repro.dist import sharding as shd
-    src = _plan_source(plan)
     if shardings is not None:
         st_sh, fr_sh = shardings
     else:
         if fsdp is None:
             fsdp = shd.fsdp_default(model.cfg, mesh)
         st_sh = shd.named(state,
-                          src.state_specs(state, mesh, model.cfg, fsdp),
+                          shd.state_specs(state, mesh, model.cfg, fsdp),
                           mesh)
         fr_sh = shd.named(frozen,
-                          src.state_specs(frozen, mesh, model.cfg, fsdp),
+                          shd.state_specs(frozen, mesh, model.cfg, fsdp),
                           mesh)
     ref = batch_example.get("tokens", batch_example.get("embeds"))
     shape = ShapeConfig("runtime", int(ref.shape[1]), int(ref.shape[0]),
                         "train")
     b_sh = shd.named(batch_example,
-                     src.batch_specs(batch_example, mesh, shape), mesh)
+                     shd.batch_specs(batch_example, mesh, shape), mesh)
     step = make_train_step(model, tcfg)
 
     def step_on_mesh(state, frozen, batch):

@@ -1,7 +1,7 @@
 import os
 
-# Tests run on the single host device (the dry-run sets its own 512-device
-# flag in its own subprocesses, never globally).
+# Tests run on the single host device (multi-device tests set their own
+# fake-device flag in their own subprocesses, never globally).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
