@@ -119,6 +119,10 @@ class ServingMetrics:
         # (slots, pages_per_seq) grid, per decode step
         self.attn_pages_live: List[float] = []
         self.steps = 0
+        # decode steps dispatched after a token's own step before the host
+        # emitted it (the plain loop reads each step one step behind)
+        self.hold_steps_sum = 0
+        self.hold_steps_max = 0
         self.wall_s = 0.0
         self._t0: Optional[float] = None
         # speculative counters (DESIGN.md §Speculation): one sample per
@@ -148,10 +152,15 @@ class ServingMetrics:
         self.requests[rid].admitted = t
         self.requests_admitted_total += 1
 
-    def on_token(self, rid: int, t: float) -> None:
+    def on_token(self, rid: int, t: float, hold: int = 0) -> None:
+        """One token emitted, `hold` decode steps after its own step was
+        dispatched (0: emitted at its own step, as primes and verify steps
+        emit theirs)."""
         r = self.requests[rid]
         r.n_tokens += 1
         self.tokens_emitted_total += 1
+        self.hold_steps_sum += hold
+        self.hold_steps_max = max(self.hold_steps_max, hold)
         if r.first_token is None:
             r.first_token = t
 
@@ -244,11 +253,12 @@ class ServingMetrics:
         primes = sorted(r.prime_s for r in self.requests.values()
                         if r.prime_s is not None)
         occ = self.occupancy
+        n_tok = self.total_tokens
         wall = self.wall_s if self._t0 is None \
             else self.wall_s + (time.perf_counter() - self._t0)
         out = {
             "n_requests": len(self.requests),
-            "total_tokens": self.total_tokens,
+            "total_tokens": n_tok,
             "steps": self.steps,
             "occupancy_mean": sum(occ) / len(occ) if occ else 0.0,
             "ttft_steps_mean": sum(ttfts) / len(ttfts) if ttfts else 0.0,
@@ -258,7 +268,9 @@ class ServingMetrics:
             "prime_s_mean": sum(primes) / len(primes) if primes else 0.0,
             "prime_s_p90": nearest_rank(primes, 0.90),
             "wall_s": wall,
-            "tokens_per_s": self.total_tokens / wall if wall > 0 else 0.0,
+            "tokens_per_s": n_tok / wall if wall > 0 else 0.0,
+            "hold_steps_mean": self.hold_steps_sum / n_tok if n_tok else 0.0,
+            "hold_steps_max": float(self.hold_steps_max),
             "queue_depth": float(self.queue_depth),
         }
         for name in self.COUNTERS:

@@ -37,13 +37,16 @@ Two throughput paths sit on top of the plain per-step decode loop:
   budget clamp inside the window), and `advance_pos` commits per-slot
   deltas — rejection is position bookkeeping, never data movement, and the
   fixed (n_slots, k+1) verify shape never recompiles.
-- **Buffered EOS detection**: the plain loop no longer syncs on every
-  step's tokens. Decode feeds its own device output back as the next
-  step's input; emitted tokens buffer on device and drain in one transfer
-  when a budget completion is due (host-known, so budget-only traffic
-  keeps its exact step timing), when the async per-slot EOS done-flag
-  comes back set, or every `eos_sync_every` steps — so EOS-enabled decode
-  no longer blocks on a host round-trip each step.
+- **One-step-behind token drain**: the plain loop never waits on the step
+  it has just dispatched. Decode feeds its own device output back as the
+  next step's input and starts that output's transfer to the host; each
+  tick then reads the step BEFORE the one it dispatched, which the chip
+  finished while the new step sat queued, so every token streams one
+  decode step after its own and the chip does not idle on the read. When
+  a budget completion falls on the newest step (host-known), that step
+  drains in the same tick too, so slots recycle at the steps an
+  unbuffered loop recycles them. EOS is found in the drained tokens, one
+  step late; the one step decoded past it is dropped at the drain.
 """
 from __future__ import annotations
 
@@ -93,10 +96,9 @@ class ContinuousScheduler:
     """Continuous-batching front end over an Engine's model/params/bank.
 
     eos_id:  optional stop token — a slot completes on emitting it (the
-             token is included in the output). Detected from the buffered
-             device-side done-flag (no per-step host round-trip); at most
-             `eos_sync_every` decode steps run past an EOS before the
-             drain discards the overshoot.
+             token is included in the output). Found on the host in the
+             drained tokens, one decode step late; the drain discards the
+             one step's overshoot.
     policy:  RequestQueue admission order ("fcfs" | "resident_first").
     bucket:  pad prime prefills to pow2 prompt buckets (bounded compile
              count); False compiles per distinct prompt length instead.
@@ -110,9 +112,6 @@ class ContinuousScheduler:
              draft-then-verify speculative decoding (DESIGN.md
              §Speculation). Greedy outputs stay token-identical to the
              non-speculative path; `metrics` grows acceptance counters.
-    eos_sync_every: max decode steps between token drains when eos_id is
-             set and no completion is otherwise due (bounds both EOS
-             detection latency and wasted overshoot steps).
     tiering: optional `serve.tiering.TieringConfig` — priority classes,
              preempt-and-resume under page/bank pressure, and host-RAM
              tiers for KV pages and adapter-bank rows (DESIGN.md
@@ -131,7 +130,6 @@ class ContinuousScheduler:
                  policy: str = "fcfs", bucket: bool = True,
                  paged: bool = True, page_size: int = 16,
                  n_pages: Optional[int] = None, drafter=None,
-                 eos_sync_every: int = 4,
                  tiering: Optional[TieringConfig] = None):
         if not engine.model.supports_slot_cache:
             raise NotImplementedError(
@@ -174,16 +172,10 @@ class ContinuousScheduler:
         self._last = [0] * self.n_slots        # per-slot last token (host)
         self._outs: Dict[int, List[int]] = {}
         self._stale = set()                    # freed, not yet reset slots
-        # buffered decode state (plain loop): device token feedback plus
-        # not-yet-drained step outputs and the async EOS done-flag
-        self.eos_sync_every = max(1, int(eos_sync_every))
+        # pipelined decode state (plain loop): device token feedback plus
+        # the dispatched steps whose tokens the host has not read yet
         self._pending: List[Tuple] = []        # (t, nt_dev, [(slot, sr)..])
         self._toks_dev = None                  # (B, 1) next-step tokens
-        self._flag_dev = None                  # (B,) device done-flags
-        self._flag_prev = None                 # last flag snapshot in flight
-        if eos_id is not None:
-            eid = int(eos_id)
-            self._or_eos = jax.jit(lambda f, nt: f | (nt == eid))
         # speculative decoding (DESIGN.md §Speculation)
         self.drafter = drafter
         if drafter is not None:
@@ -705,8 +697,6 @@ class ContinuousScheduler:
                 # now instead of carrying dead device work into the next
                 # admission cycle
                 self._pending.clear()
-                self._flag_dev = None
-                self._flag_prev = None
             self.metrics.on_cancel(rid, self.t)
             return True
         return False
@@ -759,10 +749,10 @@ class ContinuousScheduler:
 
     def _min_budget_left(self) -> int:
         """Tokens until the EARLIEST budget completion among active slots,
-        counted from the last drain — once the buffer holds that many
-        steps, a completion is inside it and must be processed (so
-        budget-only traffic drains at exactly its completion steps and
-        keeps the unbuffered loop's scheduling timing)."""
+        counted from the last drain — once the pending steps number that
+        many, a completion is inside them and must be processed this tick
+        (so slots recycle at exactly their completion steps, the
+        unbuffered loop's scheduling timing)."""
         budgets = [self.slots.state(s).budget
                    for s in self.slots.active_slots()]
         return min(budgets) if budgets else 0
@@ -776,58 +766,51 @@ class ContinuousScheduler:
                 np.asarray(self._last, np.int32)[:, None])
         nt, self.cache = self._decode(params, self.cache,
                                       {"tokens": self._toks_dev, **extra})
-        # feed the device output straight back as the next step's input —
-        # the host never sees tokens until a drain
+        # feed the device output straight back as the next step's input,
+        # and start its transfer now: the next tick reads it
         self._toks_dev = nt[:, None]
+        nt.copy_to_host_async()
         self.t += 1
         self.metrics.on_step(len(active), self.n_slots)
         self._pending.append((self.t, nt, [(s, self._sr[s]) for s in active]))
-        sync = self._min_budget_left() <= len(self._pending)
-        if self.eos_id is not None:
-            if self._flag_dev is None:
-                self._flag_dev = jnp.zeros((self.n_slots,), jnp.bool_)
-            self._flag_dev = self._or_eos(self._flag_dev, nt)
-            # the PREVIOUS flag snapshot has had a full decode dispatch to
-            # come back (copy_to_host_async below) — reading it now is
-            # effectively free, and one step of detection latency only
-            # delays the drain, never correctness
-            if self._flag_prev is not None \
-                    and bool(np.asarray(self._flag_prev).any()):
-                sync = True
-            self._flag_dev.copy_to_host_async()
-            self._flag_prev = self._flag_dev
-            if len(self._pending) >= self.eos_sync_every:
-                sync = True
-        if sync:
-            yield from self._drain()
+        # the newest step stays in flight unless a budget completion falls
+        # on it; everything before it drains now
+        due = self._min_budget_left() <= len(self._pending)
+        yield from self._drain(keep=0 if due else 1)
 
-    def _drain(self) -> Iterator[Event]:
-        """Fetch every buffered step's tokens in ONE device transfer and
-        replay them through the per-token accounting, stamped with their
-        original step times. Slots that complete mid-buffer stop
-        contributing from that step on (their later buffered tokens — the
-        decode overshoot — are discarded, exactly what the unbuffered loop
-        never generated; the device rows were dirt past their kv_len)."""
-        if not self._pending:
+    def _drain(self, keep: int = 0) -> Iterator[Event]:
+        """Read every pending step but the newest `keep` and replay its
+        tokens through the per-token accounting, stamped with their own
+        step times. Each read is `np.asarray` on that step's own output,
+        whose transfer started at dispatch: with the next step already
+        queued behind it, the read waits only for the step itself and adds
+        no device op (a stack over the pending outputs would queue behind
+        the newest step and idle the chip until it ends). Slots that
+        completed at an earlier step stop contributing — their later
+        tokens, the overshoot past an EOS found one step late, are
+        discarded: exactly what the unbuffered loop never generated (the
+        device rows were dirt past their kv_len)."""
+        n = len(self._pending) - keep
+        if n <= 0:
             return
-        pending, self._pending = self._pending, []
-        self._flag_dev = None
-        self._flag_prev = None
-        with span("sched.drain"):
-            # THE drain: one transfer per buffer  # repro: allow(host-sync)
-            arr = np.asarray(jnp.stack([nt for _, nt, _ in pending]))
-            for i, (t, _, occupants) in enumerate(pending):
+        ready, self._pending = self._pending[:n], self._pending[n:]
+        with span("sched.drain", steps=n):
+            for t, nt, occupants in ready:
+                arr = np.asarray(nt)      # one step's tokens, sent at dispatch
+                hold = int(self.t - t)    # steps dispatched after this one
                 for slot, sr in occupants:
                     if self._sr[slot] is not sr:   # finished earlier
                         continue
-                    tok = int(arr[i, slot])
+                    tok = int(arr[slot])
                     self._outs[sr.rid].append(tok)
                     self._last[slot] = tok
-                    self.metrics.on_token(sr.rid, t)
+                    self.metrics.on_token(sr.rid, t, hold=hold)
                     self.queue.note_usage(sr.request.adapter_id, 1)
                     yield ("token", sr.rid, tok, t)
                     if self.slots.note_token(slot, tok):
                         yield self._finish(slot, t)
+        if not self.slots.any_active():
+            self._pending.clear()          # only overshoot is left in flight
 
     # ---- speculative decode (DESIGN.md §Speculation) ----------------------
     def _spec_decode_once(self) -> Iterator[Event]:
@@ -904,8 +887,6 @@ class ContinuousScheduler:
             out["prefill"] = int(self._prefill._cache_size())
         if self.drafter is not None:
             out["verify"] = int(self._verify._cache_size())
-        if self.eos_id is not None:
-            out["or_eos"] = int(self._or_eos._cache_size())
         if self.tiering is not None and self.pager is not None:
             out["spill_pages"] = int(self._spill_pages._cache_size())
             out["fill_pages"] = int(self._fill_pages._cache_size())
@@ -925,8 +906,6 @@ class ContinuousScheduler:
             bounds["verify"] = 1
             # scalar rollback (drafter probe) + (B,) accept-commit deltas
             bounds["advance"] = 2
-        if self.eos_id is not None:
-            bounds["or_eos"] = 1
         if self.pager is not None:
             bounds["copy_page"] = 1
             bounds["write"] = 0            # dense-path graph, unused here
@@ -983,7 +962,8 @@ class ContinuousScheduler:
 
         Host spans (`metrics.span`): the round is `sched.tick`; inside it
         each admitted request's `sched.admit` holds its `sched.prime`, and
-        the decode step's `sched.decode` holds any `sched.drain`. On the
+        the decode step's `sched.decode` holds its `sched.drain` (`steps`:
+        the decode steps whose tokens it read). On the
         paged cache `sched.decode` carries `live_pages`: the pages the
         attention kernel visits per layer in that step."""
         with span("sched.tick"):

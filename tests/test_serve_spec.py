@@ -282,14 +282,14 @@ class TestSpecMetrics:
 
 
 # ---------------------------------------------------------------------------
-# buffered async-EOS decode loop (satellite)
+# one-step-behind token drain of the plain decode loop
 # ---------------------------------------------------------------------------
 
 class TestBufferedEOS:
     def test_eos_traffic_exact_vs_serial_reference(self):
-        """The buffered loop (device-side done-flag, drains every
-        eos_sync_every steps) truncates exactly where the serial
-        generate_requests EOS path does."""
+        """The pipelined loop (EOS read from the drained tokens, one step
+        late) truncates exactly where the serial generate_requests EOS path
+        does."""
         model, params = _base_model()
         eng = Engine(model, params, batch_slots=3, max_len=48)
         probe = _trace([10])
@@ -303,21 +303,79 @@ class TestBufferedEOS:
             eng.generate_requests(ref, eos_id=eos)
             assert r.out == ref[0].out
 
-    def test_eos_sync_every_one_matches_default(self):
-        """eos_sync_every=1 degenerates to per-step syncing; outputs (and
-        token counts) must match the buffered default exactly."""
+    def test_eos_streams_one_step_behind_exact_vs_serial(self):
+        """Each step's tokens are read one decode step after their own (the
+        EOS found there, the one step past it dropped), so no token is held
+        longer than a step and every stream still truncates where the
+        serial generate_requests EOS path does."""
         model, params = _base_model()
         eng = Engine(model, params, batch_slots=2, max_len=48)
         probe = _trace([10])
         ContinuousScheduler(eng, page_size=8).serve(probe)
         eos = probe[0].out[2]
-        a = _trace([10, 10, 10])
-        ContinuousScheduler(eng, page_size=8, eos_id=eos,
-                            eos_sync_every=1).serve(a, arrivals=[0, 0, 3])
-        b = _trace([10, 10, 3])
-        ContinuousScheduler(eng, page_size=8, eos_id=eos,
-                            eos_sync_every=4).serve(b, arrivals=[0, 0, 3])
-        assert [r.out for r in a[:2]] == [r.out for r in b[:2]]
+        reqs = _trace([10, 10, 10, 3])
+        sched = ContinuousScheduler(eng, page_size=8, eos_id=eos)
+        sched.serve(reqs, arrivals=[0, 0, 3, 3])
+        for r in reqs:
+            ref = [Request(prompt=r.prompt, max_new=r.max_new)]
+            eng.generate_requests(ref, eos_id=eos)
+            assert r.out == ref[0].out
+        s = sched.metrics.summary()
+        assert s["hold_steps_max"] == 1.0
+        assert 0.0 < s["hold_steps_mean"] < 1.0
+
+    def test_budget_only_stream_emits_before_any_completion(self):
+        """No eos_id and long budgets: decode tokens reach the host one
+        step after their own, in ticks before any slot's budget completes
+        (the buffered loop held them all until the first completion), and
+        the outputs match the serial engine."""
+        model, params = _base_model()
+        eng = Engine(model, params, batch_slots=2, max_len=48)
+        reqs = _trace([12, 16])
+        sched = ContinuousScheduler(eng, page_size=8)
+        for r in reqs:
+            sched.submit(r)
+        ticks = []
+        while len(sched.queue) or sched.slots.any_active():
+            ticks.append(sched.tick())
+        first_done = next(i for i, evs in enumerate(ticks)
+                          if any(e[0] == "done" for e in evs))
+        first_decode_tok = next(i for i, evs in enumerate(ticks)
+                                if any(e[0] == "token" and e[3] > 0
+                                       for e in evs))
+        assert first_decode_tok == 1 < first_done
+        for r in reqs:
+            assert r.out == _serial(eng, Request(prompt=r.prompt,
+                                                 max_new=r.max_new))
+        assert sched.metrics.summary()["hold_steps_max"] == 1.0
+
+    @pytest.mark.parametrize("eos", [False, True], ids=["budget", "eos"])
+    def test_at_most_one_step_pending_after_each_tick(self, eos):
+        """After every plain-loop tick at most the newest step is unread,
+        and none when a budget completion fell on it (drained in the same
+        tick, so the slot recycles at its completion step)."""
+        model, params = _base_model()
+        eng = Engine(model, params, batch_slots=2, max_len=48)
+        eos_id = None
+        if eos:
+            probe = _trace([10])
+            ContinuousScheduler(eng, page_size=8).serve(probe)
+            eos_id = probe[0].out[2]
+        reqs = _trace([6, 9, 4, 7, 5])
+        sched = ContinuousScheduler(eng, page_size=8, eos_id=eos_id)
+        for r, a in zip(reqs, [0, 0, 2, 3, 5]):
+            sched.submit(r, arrival=a)
+        budget_done = 0
+        while len(sched.queue) or sched.slots.any_active():
+            evs = sched.tick()
+            assert len(sched._pending) <= 1
+            for e in evs:
+                if e[0] == "done" and e[3] == sched.t and \
+                        len(e[2]) == reqs[e[1]].max_new:
+                    budget_done += 1
+                    assert sched._pending == []
+        assert budget_done >= 1
+        assert all(r.out is not None for r in reqs)
 
     def test_budget_only_traffic_unaffected_by_buffering(self):
         """No eos_id: the buffered loop drains exactly at budget
